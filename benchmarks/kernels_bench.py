@@ -76,7 +76,9 @@ def run(*, smoke=False, out_path=None, seed=0):
     # analytic flop/byte counts placing each kernel on the TPU roofline
     # (launch/roofline.py kernel_roof_point — shape-derived, not timed;
     # the interpret timings are the CPU correctness path, never gated).
-    from repro.launch.roofline import kernel_roof_point
+    # The roof is the target chip's, named in every row, whatever device
+    # this run is on.
+    from repro.launch.roofline import TARGET_KIND, kernel_roof_point
     NOMA_KW = dict(n0b=1e-14, pmax=0.2, bw=1e6)
     PAIR_FLOPS = 25          # sqrt + 2x log1p + div/mul chain per pair
 
@@ -90,12 +92,12 @@ def run(*, smoke=False, out_path=None, seed=0):
     n_el = bq * nq
     flops = n_el * PAIR_FLOPS
     bytes_ = n_el * (2 + 4) * 4          # 2 gain inputs, 4 fp32 outputs
-    rp = kernel_roof_point(flops, bytes_)
+    rp = kernel_roof_point(flops, bytes_, TARGET_KIND)
     rows.append({"kernel": "pairscore", "shape": f"{bq}x{nq}",
                  "us_xla_cpu": us_ps_xla, "us_interpret_cpu": us_ps_int,
                  "flops": flops, "bytes": bytes_,
                  "arith_intensity": rp.intensity, "roof_ridge": rp.ridge,
-                 "roof_bound": rp.bound,
+                 "roof_bound": rp.bound, "roof_device_kind": TARGET_KIND,
                  "roof_peak_fraction": rp.peak_fraction})
 
     for bp, cp_ in ((8, 10), (4, 256)):
@@ -111,12 +113,13 @@ def run(*, smoke=False, out_path=None, seed=0):
         # fp32 gain/t inputs broadcast from (c,), bf16 table out, fp32
         # row_min out: the fusion's whole point is the O(c) input traffic
         bytes_ = bp * (2 * cp_ * 4 + cp_ * cp_ * 2 + cp_ * 4 + 4)
-        rp = kernel_roof_point(flops, bytes_)
+        rp = kernel_roof_point(flops, bytes_, TARGET_KIND)
         rows.append({"kernel": "planner_tables", "shape": f"{bp}x{cp_}",
                      "us_xla_cpu": us_pl_xla, "us_interpret_cpu": us_pl_int,
                      "flops": flops, "bytes": bytes_,
                      "arith_intensity": rp.intensity,
                      "roof_ridge": rp.ridge, "roof_bound": rp.bound,
+                     "roof_device_kind": TARGET_KIND,
                      "roof_peak_fraction": rp.peak_fraction})
 
     result = {

@@ -49,6 +49,7 @@ from benchmarks import (
     roofline_table,
     scenario_throughput,
 )
+from repro.launch import compile_cache
 
 # every entry takes (quick, out): ``out`` is the JSON output path, or None
 # for each module's default under experiments/bench/
@@ -102,6 +103,7 @@ def check_registry() -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",

@@ -1400,6 +1400,34 @@ def _rescore_pallas(out: EngineSchedule, gains, model_bits, oma: bool,
 # ---------------------------------------------------------------------------
 
 
+def _shard_mesh(count: int, axis: str, what: str):
+    """One-axis mesh over every visible device for ``shard=True``; None on
+    a one-device host (sharding is then a no-op). A ``count`` that does not
+    divide evenly over the devices raises rather than running on one."""
+    devs = jax.devices()
+    if len(devs) == 1:
+        return None
+    if count % len(devs):
+        raise ValueError(f"shard=True: {what}={count} does not divide over "
+                         f"{len(devs)} devices")
+    from jax.sharding import Mesh
+    return Mesh(np.array(devs), (axis,))
+
+
+def _per_shard(fn, mesh, *args):
+    """``fn(*args)``; with a mesh, ``fn`` runs on each device's block of the
+    leading (batch or seed) axis of every argument and output. XLA cannot
+    partition the Mosaic kernels inside the planner, so each device plans
+    its own rows (the rows are independent). ``check_vma`` is off because
+    a ``pallas_call``'s output shapes carry no varying-axes annotation."""
+    if mesh is None:
+        return fn(*args)
+    from jax.sharding import PartitionSpec
+    spec = PartitionSpec(mesh.axis_names[0])
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(*args)
+
+
 class WirelessEngine:
     """Batched scheduler with the numpy implementation's semantics.
 
@@ -1527,7 +1555,8 @@ class WirelessEngine:
 
         ``shard=True`` splits the (embarrassingly parallel) batch across
         all visible devices via jit sharding — on CPU run with
-        ``XLA_FLAGS=--xla_force_host_platform_device_count=<cores>``.
+        ``XLA_FLAGS=--xla_force_host_platform_device_count=<cores>``. A
+        batch that does not divide over the devices raises.
         """
         b, n = np.shape(gains)
         no_budget = (isinstance(t_budget, (int, float))
@@ -1565,19 +1594,16 @@ class WirelessEngine:
             jnp.asarray(model_bits, jnp.float32), (b,))
         n_cand0 = min(self.prm.slots, n)
         n_pairs = max((n_cand0 + 1) // 2, 1)
-        if shard:
-            devs = jax.devices()
-            if len(devs) > 1 and b % len(devs) == 0:
-                from jax.sharding import (Mesh, NamedSharding,
-                                          PartitionSpec)
-                sh = NamedSharding(Mesh(np.array(devs), ("b",)),
-                                   PartitionSpec("b"))
-                gains, n_samples, cpu_freq, ages, model_bits = (
-                    jax.device_put(jnp.asarray(x, jnp.float32), sh)
-                    for x in (gains, n_samples, cpu_freq, ages, model_bits))
-                if priority is not None:
-                    priority = jax.device_put(
-                        jnp.asarray(priority, jnp.float32), sh)
+        mesh = _shard_mesh(b, "b", "batch") if shard else None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            sh = NamedSharding(mesh, PartitionSpec("b"))
+            gains, n_samples, cpu_freq, ages, model_bits = (
+                jax.device_put(jnp.asarray(x, jnp.float32), sh)
+                for x in (gains, n_samples, cpu_freq, ages, model_bits))
+            if priority is not None:
+                priority = jax.device_put(
+                    jnp.asarray(priority, jnp.float32), sh)
         pairing = self.pairing if pairing is None else pairing
         selection = self.selection if selection is None else selection
         if selection not in SELECTIONS:
@@ -1599,35 +1625,38 @@ class WirelessEngine:
             tb = (jnp.zeros((b,), jnp.float32) if no_budget
                   else jnp.broadcast_to(
                       jnp.asarray(t_budget, jnp.float32), (b,)))
-            out = _multicell_schedule_core(
-                jnp.asarray(priority, jnp.float32), gains, t_cmp,
-                n_samples, model_bits, tb,
-                jnp.asarray(cell, jnp.int32), prm=self.prm, oma=oma,
+            out = _per_shard(functools.partial(
+                _multicell_schedule_core, prm=self.prm, oma=oma,
                 pairing=pairing, selection=selection, admission=adm,
                 n_cells=n_cells, cap=cap, budget=not no_budget,
-                impl=self.impl)
+                impl=self.impl), mesh,
+                jnp.asarray(priority, jnp.float32), gains, t_cmp,
+                n_samples, model_bits, tb, jnp.asarray(cell, jnp.int32))
             if self.use_pallas and not no_budget:
                 # fast cells already scored in-kernel; the budget cells'
                 # eviction loop is XLA and gets the post-hoc rescore
-                out = self._rescore(out, gains, model_bits, oma)
+                out = self._rescore(out, gains, model_bits, oma, mesh)
             return out
         admission = resolve_admission(
             self.admission if admission is None else admission, n, n_cand0)
         if no_budget and priority is None:
             # fully fused: age priority + T_cmp + fast path in one dispatch
-            out = _fast_from_env_core(
+            out = _per_shard(functools.partial(
+                _fast_from_env_core, prm=self.prm,
+                gamma=self.flcfg.age_exponent, oma=oma, n_pairs=n_pairs,
+                n_cand0=n_cand0, pairing=pairing, selection=selection,
+                admission=admission, impl=self.impl), mesh,
                 gains, n_samples, jnp.asarray(cpu_freq, jnp.float32), ages,
-                model_bits, prm=self.prm, gamma=self.flcfg.age_exponent,
-                oma=oma, n_pairs=n_pairs, n_cand0=n_cand0, pairing=pairing,
-                selection=selection, admission=admission, impl=self.impl)
+                model_bits)
         elif no_budget:
             priority = jnp.asarray(priority, jnp.float32)
             t_cmp = self.compute_times(n_samples,
                                        jnp.asarray(cpu_freq, jnp.float32))
-            out = _fast_schedule_batch_core(
-                priority, gains, t_cmp, n_samples, model_bits, prm=self.prm,
-                oma=oma, n_pairs=n_pairs, n_cand0=n_cand0, pairing=pairing,
-                selection=selection, admission=admission, impl=self.impl)
+            out = _per_shard(functools.partial(
+                _fast_schedule_batch_core, prm=self.prm, oma=oma,
+                n_pairs=n_pairs, n_cand0=n_cand0, pairing=pairing,
+                selection=selection, admission=admission, impl=self.impl),
+                mesh, priority, gains, t_cmp, n_samples, model_bits)
         else:
             if priority is None:
                 priority = self.age_priority(ages, n_samples, gains)
@@ -1636,18 +1665,20 @@ class WirelessEngine:
                                        jnp.asarray(cpu_freq, jnp.float32))
             t_budget = jnp.broadcast_to(jnp.asarray(t_budget, jnp.float32),
                                         (b,))
-            out = _schedule_batch_core(
-                priority, gains, t_cmp, n_samples, model_bits, t_budget,
-                prm=self.prm, oma=oma, n_pairs=n_pairs, n_cand0=n_cand0,
-                pairing=pairing, selection=selection)
+            out = _per_shard(functools.partial(
+                _schedule_batch_core, prm=self.prm, oma=oma,
+                n_pairs=n_pairs, n_cand0=n_cand0, pairing=pairing,
+                selection=selection), mesh,
+                priority, gains, t_cmp, n_samples, model_bits, t_budget)
             if self.use_pallas:
-                out = self._rescore(out, gains, model_bits, oma)
+                out = self._rescore(out, gains, model_bits, oma, mesh)
         return out
 
     def _rescore(self, out: EngineSchedule, gains, model_bits,
-                 oma: bool) -> EngineSchedule:
-        return _rescore_pallas(out, gains, model_bits, oma, self.prm,
-                               self.pallas_impl)
+                 oma: bool, mesh=None) -> EngineSchedule:
+        return _per_shard(functools.partial(
+            _rescore_pallas, oma=oma, prm=self.prm, impl=self.pallas_impl),
+            mesh, out, gains, model_bits)
 
     def schedule(self, env: RoundEnv, *, t_budget: Optional[float] = None,
                  oma: bool = False, priority=None,
@@ -1694,24 +1725,22 @@ class WirelessEngine:
         n_evicted (R, S) int32, aou_hist (R, S, 7) int32 — DESIGN.md
         section 11), plus participation (S, N) and, under multi-cell,
         per-round ``handovers`` (R, S).
-        ``shard=True`` splits the independent seeds over all devices.
+        ``shard=True`` splits the independent seeds over all devices (a
+        seed count that does not divide over them raises).
         """
         gains_seq = jnp.asarray(gains_seq, jnp.float32)
         r, s, n = gains_seq.shape
         n_samples = jnp.asarray(n_samples, jnp.float32)
         cpu_freq = jnp.asarray(cpu_freq, jnp.float32)
-        if shard:
-            devs = jax.devices()
-            if len(devs) > 1 and s % len(devs) == 0:
-                from jax.sharding import (Mesh, NamedSharding,
-                                          PartitionSpec)
-                mesh = Mesh(np.array(devs), ("s",))
-                seq = NamedSharding(mesh, PartitionSpec(None, "s"))
-                per_seed = NamedSharding(mesh, PartitionSpec("s"))
-                gains_seq = jax.device_put(gains_seq, seq)
-                n_samples, cpu_freq = (
-                    jax.device_put(x, per_seed if x.ndim == 2 else seq)
-                    for x in (n_samples, cpu_freq))
+        mesh = _shard_mesh(s, "s", "n_seeds") if shard else None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            seq = NamedSharding(mesh, PartitionSpec(None, "s"))
+            per_seed = NamedSharding(mesh, PartitionSpec("s"))
+            gains_seq = jax.device_put(gains_seq, seq)
+            n_samples, cpu_freq = (
+                jax.device_put(x, per_seed if x.ndim == 2 else seq)
+                for x in (n_samples, cpu_freq))
 
         if cell_seq is not None:
             cell_seq = jnp.asarray(cell_seq, jnp.int32)
@@ -1724,7 +1753,8 @@ class WirelessEngine:
 
         return self._mc_loop(env_fn, r, model_bits, policy=policy,
                              t_budget=t_budget, seed=seed, pairing=pairing,
-                             selection=selection, admission=admission)
+                             selection=selection, admission=admission,
+                             mesh=mesh)
 
     def montecarlo_scenario(self, scenario, *, rounds: int, n_seeds: int,
                             n_clients: int, model_bits,
@@ -1748,17 +1778,11 @@ class WirelessEngine:
             key = jax.random.PRNGKey(seed)
         state, env_keys = scenario.init_and_keys(
             key, rounds, (n_seeds, n_clients))
-        if shard:
-            devs = jax.devices()
-            if len(devs) > 1 and n_seeds % len(devs) == 0:
-                from jax.sharding import (Mesh, NamedSharding,
-                                          PartitionSpec)
-                mesh = Mesh(np.array(devs), ("s",))
-                state = jax.tree.map(
-                    lambda x: jax.device_put(x, NamedSharding(
-                        mesh, PartitionSpec(*(("s",)
-                                              + (None,) * (x.ndim - 1))))),
-                    state)
+        mesh = _shard_mesh(n_seeds, "s", "n_seeds") if shard else None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            state = jax.device_put(
+                state, NamedSharding(mesh, PartitionSpec("s")))
         box = [state]
 
         def env_fn(i):
@@ -1768,13 +1792,14 @@ class WirelessEngine:
 
         return self._mc_loop(env_fn, rounds, model_bits, policy=policy,
                              t_budget=t_budget, seed=seed, pairing=pairing,
-                             selection=selection, admission=admission)
+                             selection=selection, admission=admission,
+                             mesh=mesh)
 
     def _mc_loop(self, env_fn, rounds: int, model_bits, *, policy: str,
                  t_budget: float, seed: int,
                  pairing: Optional[str] = None,
                  selection: Optional[str] = None,
-                 admission: Optional[str] = None):
+                 admission: Optional[str] = None, mesh=None):
         """R-round rollout: a Python loop of jitted per-round steps rather
         than ``lax.scan`` — on CPU the XLA while-loop runs the identical
         body ~1.7x slower than back-to-back jit dispatches. ``env_fn(i)``
@@ -1782,7 +1807,9 @@ class WirelessEngine:
         either sliced from pre-sampled arrays or stepped out of a
         scenario state. With ``FLConfig.n_cells > 1`` and a non-None
         cell, each step runs the cell-partitioned planner and the output
-        gains per-round handover counts."""
+        gains per-round handover counts. ``mesh`` (from ``shard=True``)
+        keeps the age state on the seed axis and plans each device's seeds
+        on that device."""
         pairing = self.pairing if pairing is None else pairing
         selection = self.selection if selection is None else selection
         if selection not in SELECTIONS:
@@ -1816,6 +1843,11 @@ class WirelessEngine:
                     n_pairs = max((n_cand0 + 1) // 2, 1)
                     ages = jnp.ones((s, n), jnp.float32)
                     part = jnp.zeros((s, n), jnp.float32)
+                    if mesh is not None:
+                        from jax.sharding import NamedSharding, PartitionSpec
+                        ages, part = jax.device_put(
+                            (ages, part),
+                            NamedSharding(mesh, PartitionSpec("s")))
                     sp.note(s=s, n=n, cold=trace.cold(
                         ("mc", s, n, policy, pairing, selection, admission,
                          float(t_budget), multicell)))
@@ -1830,7 +1862,7 @@ class WirelessEngine:
                     n_cand0=n_cand0,
                     pairing=pairing, selection=selection,
                     admission=admission, impl=self.impl,
-                    n_cells=n_cells if multicell else 1, cap=cap)
+                    n_cells=n_cells if multicell else 1, cap=cap, mesh=mesh)
                 t_rounds.append(t_round)
                 n_sels.append(n_sel)
                 max_ages.append(max_age)
@@ -1864,7 +1896,7 @@ class WirelessEngine:
                                              "n_cand0", "pairing",
                                              "selection", "admission",
                                              "impl", "n_cells",
-                                             "cap"))
+                                             "cap", "mesh"))
 def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
                      model_bits, round_idx, cell=None, *,
                      prm: EngineParams,
@@ -1874,7 +1906,7 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
                      selection: str = "greedy_set",
                      admission: str = "full_sort",
                      impl: str = "xla",
-                     n_cells: int = 1, cap: int = 0):
+                     n_cells: int = 1, cap: int = 0, mesh=None):
     """One Monte-Carlo round over all seeds; every policy in
     ``fl.rounds.POLICIES`` resolves to a priority vector here
     (``age_noma_budget`` is age priority + the caller's positive
@@ -1883,7 +1915,8 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
     routes through the cell-partitioned planner (``n_cand0``/``n_pairs``
     are then the per-cell values for capacity ``cap``). ``impl`` routes
     the fast paths' scoring in-kernel; the budget path rescores post-hoc
-    (see ``_rescore_pallas``)."""
+    (see ``_rescore_pallas``). With a ``mesh`` the priorities are drawn
+    over the whole seed axis and each device plans its own seeds."""
     s, n = gains.shape
     oma = policy == "oma_age"
     t_cmp = _compute_times(prm, n_samples, cpu_freq)
@@ -1899,34 +1932,42 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
                                 gains.shape)
     else:
         raise ValueError(f"unknown montecarlo policy {policy!r}")
-    if cell is not None and n_cells > 1:
-        tb = jnp.full((s,), t_budget, jnp.float32)
-        sched = _multicell_schedule(
-            prio, gains, t_cmp, n_samples, mb, tb, cell, prm=prm, oma=oma,
-            pairing=pairing, selection=selection, admission=admission,
-            n_cells=n_cells, cap=cap, budget=t_budget > 0.0, impl=impl)
-        if t_budget > 0.0 and impl != "xla":
-            sched = _rescore_pallas(sched, gains, mb, oma, prm, impl)
-    elif t_budget <= 0.0:
-        def step(p, g, tc, ns, mbx):
-            return _fast_schedule_batch(p, g, tc, ns, mbx, prm, oma,
-                                        n_pairs, n_cand0, pairing,
-                                        selection, admission, impl)
 
-        sub = _seg_subchunk(s, n) if admission == "segmented" else 0
-        if sub:
-            sched = _scan_subchunks(
-                step, (prio, gains, t_cmp, n_samples, mb), s, sub)
-        else:
-            sched = step(prio, gains, t_cmp, n_samples, mb)
-    else:
-        tb = jnp.full((s,), t_budget, jnp.float32)
+    def plan(prio, gains, t_cmp, n_samples, mb, cell):
+        rows = gains.shape[0]
+        if cell is not None and n_cells > 1:
+            tb = jnp.full((rows,), t_budget, jnp.float32)
+            sched = _multicell_schedule(
+                prio, gains, t_cmp, n_samples, mb, tb, cell, prm=prm,
+                oma=oma, pairing=pairing, selection=selection,
+                admission=admission, n_cells=n_cells, cap=cap,
+                budget=t_budget > 0.0, impl=impl)
+            if t_budget > 0.0 and impl != "xla":
+                sched = _rescore_pallas(sched, gains, mb, oma, prm, impl)
+            return sched
+        if t_budget <= 0.0:
+            def step(p, g, tc, ns, mbx):
+                return _fast_schedule_batch(p, g, tc, ns, mbx, prm, oma,
+                                            n_pairs, n_cand0, pairing,
+                                            selection, admission, impl)
+
+            sub = (_seg_subchunk(rows, n) if admission == "segmented"
+                   else 0)
+            if sub:
+                return _scan_subchunks(
+                    step, (prio, gains, t_cmp, n_samples, mb), rows, sub)
+            return step(prio, gains, t_cmp, n_samples, mb)
+        tb = jnp.full((rows,), t_budget, jnp.float32)
         one = functools.partial(_schedule_one, prm=prm, oma=oma,
                                 n_pairs=n_pairs, n_cand0=n_cand0,
                                 pairing=pairing, selection=selection)
         sched = jax.vmap(one)(prio, gains, t_cmp, n_samples, mb, tb)
         if impl != "xla":
             sched = _rescore_pallas(sched, gains, mb, oma, prm, impl)
+        return sched
+
+    sched = _per_shard(plan, mesh, prio, gains, t_cmp, n_samples, mb,
+                       cell if n_cells > 1 else None)
     sel = sched.selected
     ages2 = jnp.where(sel, 1.0, ages + 1.0)
     diag = schedule_diag(sched, ages2)

@@ -24,7 +24,7 @@ MC_POLICIES = POLICIES
 
 def run_experiment(model_cfg: ModelConfig, fl: FLConfig, nomacfg: NOMAConfig,
                    task: TaskConfig, policy: str, *, rounds=None,
-                   verbose=False, seed=None, agg_impl="xla",
+                   verbose=False, seed=None, agg_impl=None,
                    predictor=None, pairing=None, selection=None) -> History:
     server = FLServer(model_cfg, fl, nomacfg, task, policy=policy,
                       seed=seed, agg_impl=agg_impl, predictor=predictor,

@@ -46,6 +46,7 @@ from repro.fl.aggregate import aggregate_deltas, apply_aggregate, \
     blend_deltas
 from repro.fl.client import LocalTrainer
 from repro.fl.predictor import UpdatePredictor
+from repro.kernels.backend import resolve_backend, resolve_impl
 from repro.models import zoo
 from repro.obs import RunLedger, json_safe, trace
 from repro.sim import NumpyScenario, get_scenario_config
@@ -92,7 +93,8 @@ class History:
 class FLServer:
     def __init__(self, model_cfg: ModelConfig, fl: FLConfig,
                  nomacfg: NOMAConfig, task: TaskConfig, *,
-                 policy: str = "age_noma", agg_impl: str = "xla",
+                 policy: str = "age_noma",
+                 agg_impl: Optional[str] = None,
                  eval_every: int = 5, seed: Optional[int] = None,
                  predictor: Optional[str] = None,
                  engine: Optional[str] = None,
@@ -119,7 +121,11 @@ class FLServer:
         self.noma = nomacfg
         self.task = task
         self.policy = policy
-        self.agg_impl = agg_impl
+        # FedAvg reduction impl: by default the one FLConfig.kernel_backend
+        # resolves to here (the compiled fedagg kernel on a TPU, the XLA
+        # twin on the CPU)
+        self.agg_impl = (resolve_backend(fl.kernel_backend).impl
+                         if agg_impl is None else resolve_impl(agg_impl))
         self.eval_every = eval_every
         self.predictor_mode = fl.predictor if predictor is None else predictor
         # batched wireless engine (core/engine.py) behind FLConfig.engine;

@@ -22,10 +22,12 @@ detection, eagerly (at engine construction, not deep inside a jit trace):
                    fallback, so parity tiers exercise the kernel body.
   pallas_interpret interpret mode unconditionally (tests, debugging).
 
-Capability detection actually compiles a trivial kernel once per process
-(``functools.lru_cache``) rather than trusting the platform string: a TPU
-platform with a broken Mosaic toolchain, or a GPU without Triton support,
-degrades honestly instead of exploding inside the engine's first round.
+Capability detection compiles a trivial kernel once per process
+(``functools.lru_cache``) rather than trusting the platform string. On a
+platform that has a compiled lowering (TPU, GPU) a failed probe raises
+with the compiler's error: a broken Mosaic or Triton toolchain stops the
+run at construction instead of quietly running the XLA twin under a
+Pallas request.
 """
 from __future__ import annotations
 
@@ -63,8 +65,11 @@ def _probe_kernel(x_ref, o_ref):
 @functools.lru_cache(maxsize=None)
 def compiled_flavor():
     """``"mosaic" | "triton" | None``: the compiled Pallas lowering this
-    process can actually use, probed by compiling a trivial kernel."""
-    flavor = _FLAVORS.get(jax.default_backend())
+    process uses, probed by compiling a trivial kernel. None only where
+    the platform has no compiled lowering (the CPU); on a platform that
+    has one, a probe that fails to compile or run raises."""
+    platform = jax.default_backend()
+    flavor = _FLAVORS.get(platform)
     if flavor is None:
         return None
     try:
@@ -73,8 +78,10 @@ def compiled_flavor():
             out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
         )(jnp.zeros((8, 128), jnp.float32))
         jax.block_until_ready(out)
-    except Exception:  # lowering/toolchain failure -> no compiled path
-        return None
+    except Exception as e:  # noqa: BLE001 — re-raised with the platform
+        raise RuntimeError(
+            f"the {flavor} Pallas lowering failed its probe compile on "
+            f"platform {platform!r}: {e}") from e
     return flavor
 
 

@@ -3,8 +3,8 @@
 ``impl`` (validated by ``kernels.backend.resolve_impl`` — the ONE resolver
 shared with backend detection; unknown strings raise eagerly instead of
 falling through to the Pallas branch):
-  "xla"      pure-jnp implementation (default on CPU; what the dry-run and
-             the FL runtime use on this container)
+  "xla"      pure-jnp implementation (what ``auto`` resolves to on the
+             CPU; the twin every compiled kernel is checked against)
   "pallas"   the TPU kernel (compiled for TPU targets)
   "interpret" the TPU kernel executed by the Pallas interpreter on CPU —
              used by the correctness tests to validate the kernel body.
@@ -26,29 +26,17 @@ from repro.kernels import wkv6 as _wkv6
 from repro.kernels.backend import resolve_impl
 
 
-def _pad_to(x, axis, multiple):
-    size = x.shape[axis]
-    pad = (-size) % multiple
-    if pad == 0:
-        return x, size
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), size
-
-
 def weighted_sum(stacked, weights, *, impl: str = "xla",
-                 block_n: int = 65_536):
-    """stacked (C, *shape); weights (C,) -> (*shape,) fp32 weighted sum."""
+                 block_n: int = _fedagg.BLOCK_N):
+    """stacked (C, *shape); weights (C,) -> (*shape,) fp32 weighted sum.
+    ``block_n`` caps the kernel's N tile (``fedagg.block_shape``)."""
     c = stacked.shape[0]
     flat = stacked.reshape(c, -1)
     if resolve_impl(impl) == "xla":
         out = _ref.weighted_sum_ref(flat, weights)
     else:
-        n = flat.shape[1]
-        bn = min(block_n, max(512, 1 << (n - 1).bit_length()))
-        padded, orig = _pad_to(flat, 1, bn)
-        out = _fedagg.fedagg_pallas(padded, weights, block_n=bn,
-                                    interpret=(impl == "interpret"))[:orig]
+        out = _fedagg.fedagg_pallas(flat, weights, block_n=block_n,
+                                    interpret=(impl == "interpret"))
     return out.reshape(stacked.shape[1:])
 
 

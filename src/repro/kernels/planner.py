@@ -23,11 +23,13 @@ values BEFORE the bf16 round-trip, so the scalar decisions the planner
 makes are full fp32; the table itself carries bf16's ~3 decimal digits,
 validated against the fp64 numpy reference in the parity tier.
 
-Tiling: grid (B, c/128); each step holds the full gain row (1, cp) plus a
-128-column slab and emits one (cp, 128) table tile. Row/column reductions
-accumulate across column steps into revisited output blocks (sequential
-grid order, ``@pl.when`` first-step init — the fedagg/pairscore idiom).
-The (1, cp) -> (cp, 1) gain relayout is a Mosaic vector transpose.
+Tiling: grid (B, c/128); each step holds the full strong-side gain column
+(cp, 1) plus a 128-lane weak-side slab and emits one (cp, 128) table tile.
+The strong side is handed in already as a column, so the kernel needs no
+lane-to-sublane relayout. Row/column reductions accumulate across column
+steps into revisited output blocks (sequential grid order, ``@pl.when``
+first-step init — the fedagg/pairscore idiom); ``t_sw`` is kept as a
+lane-dense (1, 128) row because Mosaic cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
@@ -44,43 +46,45 @@ LANES = 128
 _EPS = 1e-9          # rate floor shared with pairscore.completion_table
 
 
-def _planner_kernel(gf_ref, tf_ref, gc_ref, tc_ref, mb_ref,
+def _planner_kernel(gr_ref, tr_ref, gc_ref, tc_ref, mb_ref,
                     tab_ref, rmin_ref, tsw_ref, *,
                     n0b, pmax, bw, oma, c, m, c_pair):
     j = pl.program_id(1)
-    cp = gf_ref.shape[1]
-    g_rows = gf_ref[0, :]                    # (cp,) strong-side gains
-    t_rows = tf_ref[0, :]
-    g_cols = gc_ref[0, :]                    # (LANES,) weak-side gains
-    t_cols = tc_ref[0, :]
-    mb = mb_ref[0, 0]
+    cp = gr_ref.shape[1]
+    g_rows = gr_ref[0]                       # (cp, 1) strong-side gains
+    t_rows = tr_ref[0]
+    g_cols = gc_ref[0]                       # (1, LANES) weak-side gains
+    t_cols = tc_ref[0]
+    mb = mb_ref[0]                           # (1, LANES), one value per row
 
-    gi = jnp.broadcast_to(g_rows.reshape(cp, 1), (cp, LANES))
-    gj = jnp.broadcast_to(g_cols.reshape(1, LANES), (cp, LANES))
+    gi = jnp.broadcast_to(g_rows, (cp, LANES))
+    gj = jnp.broadcast_to(g_cols, (cp, LANES))
     _, _, r_i, r_j = _pair_math(gi, gj, n0b=n0b, pmax=pmax, bw=bw, oma=oma)
-    comp = jnp.maximum(
-        t_rows.reshape(cp, 1) + mb / jnp.maximum(r_i, _EPS),
-        t_cols.reshape(1, LANES) + mb / jnp.maximum(r_j, _EPS))
+    comp = jnp.maximum(t_rows + mb / jnp.maximum(r_i, _EPS),
+                       t_cols + mb / jnp.maximum(r_j, _EPS))
     tab_ref[0] = comp.astype(tab_ref.dtype)
 
     rowid = jax.lax.broadcasted_iota(jnp.int32, (cp, LANES), 0)
     colid = jax.lax.broadcasted_iota(jnp.int32, (cp, LANES), 1) + j * LANES
     valid = (rowid < c) & (colid < c) & (rowid != colid)
-    rm = jnp.min(jnp.where(valid, comp, jnp.inf), axis=1)          # (cp,)
+    rm = jnp.min(jnp.where(valid, comp, jnp.inf), axis=1,
+                 keepdims=True)                                  # (cp, 1)
     # strong_weak anti-diagonal: rank p pairs with rank c_pair-1-p; the
     # strong half (p < m) hits each pair's table entry exactly once.
     pair_m = (colid == c_pair - 1 - rowid) & (rowid < m)
-    tmax = jnp.max(jnp.where(pair_m, comp, -jnp.inf))
+    tmax = jnp.max(jnp.where(pair_m, comp, -jnp.inf), axis=0, keepdims=True)
+    tmax = jnp.broadcast_to(jnp.max(tmax, axis=1, keepdims=True),
+                            (1, LANES))                          # lane-dense
 
     @pl.when(j == 0)
     def _init():
-        rmin_ref[0, :] = rm
-        tsw_ref[0, 0] = tmax
+        rmin_ref[0] = rm
+        tsw_ref[0] = tmax
 
     @pl.when(j > 0)
     def _acc():
-        rmin_ref[0, :] = jnp.minimum(rmin_ref[0, :], rm)
-        tsw_ref[0, 0] = jnp.maximum(tsw_ref[0, 0], tmax)
+        rmin_ref[0] = jnp.minimum(rmin_ref[0], rm)
+        tsw_ref[0] = jnp.maximum(tsw_ref[0], tmax)
 
 
 @functools.partial(
@@ -109,34 +113,38 @@ def planner_tables_pallas(g_sorted, t_cmp_sorted, model_bits, *,
     mb = jnp.broadcast_to(jnp.asarray(model_bits, jnp.float32), lead)
     g2 = g.reshape(b, c)
     t2 = t.reshape(b, c)
-    mb2 = mb.reshape(b, 1)
     cp = c + (-c) % LANES
     if cp != c:
         g2 = jnp.pad(g2, ((0, 0), (0, cp - c)))
         t2 = jnp.pad(t2, ((0, 0), (0, cp - c)))
     c_pair = c - (c % 2)
     m = c_pair // 2
-    grid = (b, cp // LANES)
-    full = pl.BlockSpec((1, cp), lambda i, j: (i, 0))
-    col = pl.BlockSpec((1, LANES), lambda i, j: (i, j))
+    # Every block's last two dims are (8k, 128k) or the array's own: the
+    # strong side enters as (b, cp, 1) columns, the weak side as (b, 1, cp)
+    # rows, model_bits and t_sw as lane-dense (b, 1, LANES) rows.
+    def per_row(i, j):
+        return i, 0, 0
+
+    full_col = pl.BlockSpec((1, cp, 1), per_row)
+    slab = pl.BlockSpec((1, 1, LANES), lambda i, j: (i, 0, j))
+    lane_row = pl.BlockSpec((1, 1, LANES), per_row)
     kernel = functools.partial(_planner_kernel, n0b=n0b, pmax=pmax, bw=bw,
                                oma=oma, c=c, m=m, c_pair=c_pair)
     tab, rmin, tsw = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[full, full, col, col,
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
+        grid=(b, cp // LANES),
+        in_specs=[full_col, full_col, slab, slab, lane_row],
         out_specs=(pl.BlockSpec((1, cp, LANES), lambda i, j: (i, 0, j)),
-                   full,
-                   pl.BlockSpec((1, 1), lambda i, j: (i, 0))),
+                   full_col, lane_row),
         out_shape=(jax.ShapeDtypeStruct((b, cp, cp), table_dtype),
-                   jax.ShapeDtypeStruct((b, cp), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 1), jnp.float32)),
+                   jax.ShapeDtypeStruct((b, cp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, LANES), jnp.float32)),
         interpret=interpret,
-    )(g2, t2, g2, t2, mb2)
+    )(g2[:, :, None], t2[:, :, None], g2[:, None, :], t2[:, None, :],
+      jnp.broadcast_to(mb.reshape(b, 1, 1), (b, 1, LANES)))
     table = tab[:, :c, :c].reshape(lead + (c, c))
-    row_min = rmin[:, :c].reshape(lead + (c,))
-    t_sw = tsw[:, 0].reshape(lead)
+    row_min = rmin[:, :c, 0].reshape(lead + (c,))
+    t_sw = tsw[:, 0, 0].reshape(lead)
     if m == 0:          # no pairs (c <= 1): the -inf identity never updates
         t_sw = jnp.zeros_like(t_sw)
     return table, row_min, t_sw

@@ -7,9 +7,11 @@ import jax.numpy as jnp
 
 
 def weighted_sum_ref(updates, weights):
-    """updates (C, N), weights (C,) -> (N,) fp32 weighted sum."""
+    """updates (C, N), weights (C,) -> (N,) fp32 weighted sum (HIGHEST:
+    a TPU's default matmul precision would round the operands to bf16)."""
     return jnp.einsum("cn,c->n", updates.astype(jnp.float32),
-                      weights.astype(jnp.float32))
+                      weights.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def wkv6_ref(r, k, v, w_log, u, s0):

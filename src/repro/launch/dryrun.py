@@ -210,12 +210,13 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = RL.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
 
     rf = RL.build_roofline(
         arch=arch, shape=shape_name, mesh_name=mesh_name, chips=chips,
-        cost=cost, mem=mem, hlo=hlo, scan_trips=scan_trips,
+        device_kind=RL.TARGET_KIND, cost=cost, mem=mem, hlo=hlo,
+        scan_trips=scan_trips,
         outer_flops_per_dev=outer + head,  # head counted once in raw
         model_flops=analytic_model_flops(cfg, shape),
         note=variant.get("note", ""))

@@ -8,30 +8,22 @@ pure data parallelism across pods (hierarchical FedAvg psum).
 from __future__ import annotations
 
 import jax
-
-
-def axis_type_kwargs(n_axes: int) -> dict:
-    """jax.sharding.AxisType landed after 0.4.x; older jax defaults every
-    axis to Auto, so omitting the kwarg there is equivalent. Shared by the
-    mesh builders here and the test subprocess scripts."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever-fits mesh for CPU smoke tests (n devices -> (n/model, model))."""
     n = len(jax.devices())
     data = max(1, n // model)
-    return jax.make_mesh(
-        (data, model), ("data", "model"), **axis_type_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_info(mesh) -> "MeshInfo":

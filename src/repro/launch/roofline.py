@@ -1,7 +1,8 @@
 """Roofline-term derivation from the compiled dry-run artifact.
 
-Hardware constants (TPU v5e target):
-    197 TFLOP/s bf16 per chip | 819 GB/s HBM | ~50 GB/s/link ICI
+Hardware constants come from ``CHIP_PEAKS``, keyed by
+``jax.Device.device_kind``; a device that is not in the table is an error,
+never a default.
 
 ``compiled.cost_analysis()`` reports PER-DEVICE flops/bytes, with every
 ``lax.scan`` body counted ONCE (verified empirically — see DESIGN.md
@@ -25,9 +26,31 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # B/s / chip
-ICI_BW = 50e9              # B/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # interconnect bytes/s per link
+
+
+# Published per-chip peaks by ``device_kind``. "TPU v5 lite" is the TPU
+# v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s
+# HBM, 1,600 Gbit/s of ICI per chip over four links (50 GB/s each).
+CHIP_PEAKS = {"TPU v5 lite": ChipPeaks(197e12, 819e9, 50e9)}
+# the chip the dry-run pods and the analytic kernel roofs are drawn for
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; ValueError for a chip not in the table."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r} (known: {sorted(CHIP_PEAKS)})"
+                         ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -70,13 +93,6 @@ def _wire_bytes(kind: str, nbytes: int, g: int) -> float:
     if kind in ("all-gather", "reduce-scatter", "all-to-all"):
         return nbytes * frac
     return float(nbytes)                   # collective-permute
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns ``[dict]`` on jax<=0.4.x and a
-    plain dict on newer jax; normalize to the dict."""
-    cost = compiled.cost_analysis()
-    return cost[0] if isinstance(cost, list) else cost
 
 
 @dataclasses.dataclass
@@ -193,23 +209,25 @@ def collective_stats(hlo: str) -> CollectiveStats:
 class RoofPoint:
     """Placement of ONE kernel on the chip roofline: where its analytic
     arithmetic intensity (flop/byte) falls relative to the ridge point
-    ``PEAK_FLOPS / HBM_BW`` and what fraction of peak the roof allows
+    ``flops / hbm_bw`` of the chip and what fraction of peak the roof allows
     there. Shape-derived, not timed — the kernels_bench rows carry it so
     the roofline table can say WHY a kernel is bandwidth-bound."""
     flops: float
     bytes: float
     intensity: float         # flop / byte
-    ridge: float             # PEAK_FLOPS / HBM_BW (flop/byte)
+    ridge: float             # peak flops / HBM bandwidth (flop/byte)
     bound: str               # "memory" when intensity < ridge else "compute"
     peak_fraction: float     # attainable FLOP/s at this intensity / peak
     t_compute: float         # seconds at peak compute
     t_memory: float          # seconds at peak HBM bandwidth
 
 
-def kernel_roof_point(flops: float, bytes_: float, *,
-                      peak_flops: float = PEAK_FLOPS,
-                      hbm_bw: float = HBM_BW) -> RoofPoint:
-    """Place a kernel with analytic ``flops``/``bytes_`` on the roofline."""
+def kernel_roof_point(flops: float, bytes_: float,
+                      device_kind: str) -> RoofPoint:
+    """Place a kernel with analytic ``flops``/``bytes_`` on the roofline of
+    ``device_kind`` (``chip_peaks``)."""
+    peaks = chip_peaks(device_kind)
+    peak_flops, hbm_bw = peaks.flops, peaks.hbm_bw
     intensity = flops / max(bytes_, 1.0)
     ridge = peak_flops / hbm_bw
     attainable = min(peak_flops, intensity * hbm_bw)
@@ -256,9 +274,10 @@ class Roofline:
 
 
 def build_roofline(*, arch: str, shape: str, mesh_name: str, chips: int,
-                   cost: dict, mem, hlo: str, scan_trips: int,
-                   outer_flops_per_dev: float, model_flops: float,
-                   note: str = "") -> Roofline:
+                   device_kind: str, cost: dict, mem, hlo: str,
+                   scan_trips: int, outer_flops_per_dev: float,
+                   model_flops: float, note: str = "") -> Roofline:
+    peaks = chip_peaks(device_kind)
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     inner_f = max(raw_flops - outer_flops_per_dev, 0.0)
@@ -269,11 +288,11 @@ def build_roofline(*, arch: str, shape: str, mesh_name: str, chips: int,
     # nested scans (the flash-attention q/kv loops) are ALSO counted once by
     # HLO cost analysis; the analytic MODEL_FLOPS floor catches that
     # undercount, so the compute term takes the max of the two estimates.
-    t_c = max(flops, model_flops / chips) / PEAK_FLOPS
-    t_m = bytes_ / HBM_BW
+    t_c = max(flops, model_flops / chips) / peaks.flops
+    t_m = bytes_ / peaks.hbm_bw
     t_floor = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               - mem.alias_size_in_bytes) / HBM_BW
-    t_l = colls.wire_bytes / ICI_BW
+               - mem.alias_size_in_bytes) / peaks.hbm_bw
+    t_l = colls.wire_bytes / peaks.ici_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_l}
     bott = max(terms, key=terms.get)
     mem_per_chip = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

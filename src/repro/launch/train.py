@@ -23,9 +23,11 @@ from repro.configs import ARCH_IDS, FLConfig, NOMAConfig, get_config
 from repro.data import TaskConfig, bayes_optimal_accuracy
 from repro.fl import FLServer
 from repro import checkpoint as ckpt
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_135m", choices=ARCH_IDS)
     ap.add_argument("--policy", default="age_noma_budget",

@@ -66,6 +66,26 @@ class TestResolver:
                    for w in rec)
 
 
+    def test_failed_probe_raises_on_compiled_platform(self, monkeypatch):
+        """On a platform with a compiled lowering, a probe that fails to
+        compile raises with the compiler's error — auto never quietly
+        degrades to the XLA twin there."""
+        from repro.kernels import backend
+
+        def broken(*args, **kwargs):
+            raise ValueError("Mosaic toolchain missing")
+
+        monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(backend.pl, "pallas_call", broken)
+        backend.compiled_flavor.cache_clear()
+        try:
+            with pytest.raises(RuntimeError,
+                               match="mosaic.*probe.*toolchain missing"):
+                resolve_backend("auto")
+        finally:
+            backend.compiled_flavor.cache_clear()
+
+
 class TestConfigField:
     def test_default_is_auto(self):
         assert FLConfig().kernel_backend == "auto"

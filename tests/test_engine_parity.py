@@ -304,3 +304,82 @@ class TestBatchedConsistency:
             assert out["t_round"][r, 0] == pytest.approx(ref.t_round,
                                                          rel=1e-4)
             assert int(out["max_age"][r, 0]) == int(ages.max())
+
+
+class TestShardedSweep:
+    """``shard=True`` on a 4-device CPU host (subprocess: the device-count
+    flag must not leak into this process). The planner runs per device
+    under ``shard_map`` (Mosaic kernels cannot be partitioned by XLA), so
+    the sharded sweep and batch equal the one-device ones, the outputs
+    span all four devices, and a count that does not divide over the
+    devices raises instead of quietly running on one."""
+
+    SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["REPRO_LEDGER"] = "0"
+import sys
+sys.path.insert(0, "src")
+import jax
+import numpy as np
+from repro.configs import FLConfig, NOMAConfig
+from repro.core.engine import WirelessEngine
+from repro.fl.rounds import run_montecarlo
+from repro.sim import as_scenario
+
+assert len(jax.devices()) == 4
+ncfg = NOMAConfig(n_subchannels=3)
+kw = dict(n_clients=300, n_seeds=8, rounds=3, scenario="vehicular",
+          policies=("age_noma", "random"), pairing="%s",
+          kernel_backend="pallas_interpret")
+one = run_montecarlo(ncfg, **kw)
+four = run_montecarlo(ncfg, shard=True, **kw)
+for pol in kw["policies"]:
+    for k in ("participation", "final_ages", "n_selected", "max_age"):
+        np.testing.assert_array_equal(four[pol][k], one[pol][k])
+    np.testing.assert_allclose(four[pol]["t_round"], one[pol]["t_round"],
+                               rtol=1e-6)
+
+eng = WirelessEngine(ncfg, FLConfig(), kernel_backend="pallas_interpret",
+                     pairing="%s")
+scn = as_scenario("vehicular", ncfg, FLConfig())
+out = eng.montecarlo_scenario(scn, rounds=2, n_seeds=8, n_clients=300,
+                              model_bits=1e6, shard=True)
+assert len(out["t_round"].sharding.device_set) == 4, out["t_round"].sharding
+
+rng = np.random.default_rng(0)
+env = (rng.exponential(1e-11, (8, 300)), rng.uniform(100, 1000, (8, 300)),
+       rng.uniform(0.5e9, 2e9, (8, 300)), rng.integers(1, 30, (8, 300)))
+b1 = eng.schedule_batch(*env, 4e6)
+b4 = eng.schedule_batch(*env, 4e6, shard=True)
+assert len(b4.t_round.sharding.device_set) == 4
+np.testing.assert_array_equal(np.asarray(b4.selected), np.asarray(b1.selected))
+np.testing.assert_allclose(np.asarray(b4.t_round), np.asarray(b1.t_round),
+                           rtol=1e-6)
+
+bad = (lambda: eng.montecarlo_scenario(scn, rounds=2, n_seeds=6,
+                                       n_clients=300, model_bits=1e6,
+                                       shard=True),
+       lambda: eng.schedule_batch(*(a[:6] for a in env), 4e6, shard=True))
+for call in bad:
+    try:
+        call()
+    except ValueError as e:
+        assert "does not divide over 4 devices" in str(e), e
+    else:
+        raise AssertionError("an indivisible shard=True did not raise")
+print("OK")
+"""
+
+    @pytest.mark.parametrize("pairing", ["strong_weak", "hungarian"])
+    def test_sharded_equals_one_device(self, pairing):
+        import os
+        import subprocess
+        import sys
+        out = subprocess.run(
+            [sys.executable, "-c", self.SCRIPT % (pairing, pairing)],
+            capture_output=True, text=True, cwd=os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__))), timeout=540)
+        assert out.returncode == 0, out.stderr[-3000:]
+        assert "OK" in out.stdout

@@ -22,6 +22,19 @@ class TestFedAgg:
         np.testing.assert_allclose(np.asarray(out_pal), np.asarray(out_ref),
                                    rtol=tol, atol=tol)
 
+    @pytest.mark.parametrize("c,n", [(50, 3000), (200, 1000)])
+    def test_c_tiles_within_fp32_summation_bound(self, c, n):
+        """Cohorts past one C tile (200 = 128 + a ragged 72) accumulate
+        across grid steps; each output stays within the fp32 summation
+        bound c * eps * sum|w u| of the fp64 weighted sum."""
+        u = jax.random.normal(jax.random.PRNGKey(c), (c, n), jnp.float32)
+        w = jax.random.uniform(jax.random.PRNGKey(c + 1), (c,))
+        out = np.asarray(ops.weighted_sum(u, w, impl="interpret"),
+                         np.float64)
+        u64, w64 = np.asarray(u, np.float64), np.asarray(w, np.float64)
+        bound = c * np.finfo(np.float32).eps * (np.abs(w64) @ np.abs(u64))
+        assert np.all(np.abs(out - w64 @ u64) <= bound)
+
     @pytest.mark.slow
     @given(st.integers(1, 12), st.integers(1, 5000),
            st.integers(0, 2 ** 31 - 1))

@@ -353,7 +353,8 @@ class TestDesignRef:
         assert names(fs) == ["design-ref"]
 
 
-GITIGNORE_OK = "__pycache__/\n*.pyc\nexperiments/runs/\n"
+GITIGNORE_OK = ("__pycache__/\n*.pyc\nexperiments/runs/\n.hypothesis/\n"
+                ".jax_cache/\n")
 
 
 class TestRepoHygiene:
@@ -371,10 +372,19 @@ class TestRepoHygiene:
                   tracked_files=["experiments/runs/r1/ledger.jsonl"])
         assert names(fs) == ["repo-hygiene"]
 
+    @pytest.mark.parametrize("path", [
+        ".hypothesis/constants/0123abcd",
+        ".jax_cache/jit_step-0123abcd-cache"])
+    def test_tracked_cache_dir_fires(self, path):
+        fs = lint({}, rules=["repo-hygiene"], gitignore=GITIGNORE_OK,
+                  tracked_files=[path])
+        assert names(fs) == ["repo-hygiene"]
+
     def test_missing_gitignore_pattern_fires(self):
         fs = lint({}, rules=["repo-hygiene"], gitignore="*.pyc\n",
                   tracked_files=[])
-        assert len(fs) == 2  # __pycache__/ and experiments/runs/ missing
+        # __pycache__/, experiments/runs/, .hypothesis/, .jax_cache/
+        assert len(fs) == 4
 
 
 # ---------------------------------------------------------------------------

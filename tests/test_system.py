@@ -71,8 +71,8 @@ from repro.launch.dryrun import abstract_params_and_specs
 from repro.launch import roofline as RL
 from repro.launch.mesh import mesh_info
 
-from repro.launch.mesh import axis_type_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_type_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 minfo = mesh_info(mesh)
 cfg = dataclasses.replace(get_config("%s").reduced(), vocab_size=64)
 shape = ShapeConfig("t", 64, 8, "train")
@@ -92,7 +92,7 @@ with mesh:
                       out_shardings=(pshard, ms)).lower(params, bshapes)
 compiled = lowered.compile()
 mem = compiled.memory_analysis()
-cost = RL.cost_analysis_dict(compiled)
+cost = compiled.cost_analysis()
 stats = RL.collective_stats(compiled.as_text())
 assert mem.temp_size_in_bytes > 0
 assert cost["flops"] > 0
@@ -125,8 +125,8 @@ import jax, jax.numpy as jnp, dataclasses
 from repro.configs import get_config
 from repro.models import layers as L
 
-from repro.launch.mesh import axis_type_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_type_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dataclasses.replace(get_config("llama4_maverick_400b_a17b").reduced(),
                           n_heads=5, n_kv_heads=1, head_dim=16)
 B, S = 4, 64
@@ -190,3 +190,58 @@ ENTRY %main (a: f32[4]) -> f32[4] {
         assert trips.get("body") == 48
         mult = _region_multipliers(comps, trips)
         assert mult.get("body") == 48
+
+
+class TestChipPeaks:
+    def test_v5e_peaks_published(self):
+        from repro.launch.roofline import TARGET_KIND, chip_peaks
+        peaks = chip_peaks(TARGET_KIND)
+        assert TARGET_KIND == "TPU v5 lite"
+        assert (peaks.flops, peaks.hbm_bw) == (197e12, 819e9)
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite"])
+    def test_unknown_device_kind_rejected(self, kind):
+        from repro.launch.roofline import chip_peaks, kernel_roof_point
+        with pytest.raises(ValueError, match="no published peaks"):
+            chip_peaks(kind)
+        with pytest.raises(ValueError, match="no published peaks"):
+            kernel_roof_point(1e9, 1e9, kind)
+
+    def test_kernel_roof_point_on_v5e(self):
+        from repro.launch.roofline import TARGET_KIND, kernel_roof_point
+        rp = kernel_roof_point(1e9, 1e9, TARGET_KIND)
+        assert rp.bound == "memory"
+        assert rp.ridge == pytest.approx(197e12 / 819e9)
+        assert rp.peak_fraction == pytest.approx(819e9 / 197e12)
+
+
+class TestCompileCache:
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, with nothing set in code, and otherwise to one fixed,
+    git-ignored directory in the checkout."""
+
+    def test_unset_goes_to_fixed_checkout_path(self, monkeypatch):
+        import pathlib
+
+        import jax
+
+        from repro.launch import compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = compile_cache.enable()
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(path) == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+    def test_env_var_is_left_to_jax(self, monkeypatch, tmp_path):
+        import jax
+
+        from repro.launch import compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before

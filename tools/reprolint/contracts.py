@@ -358,23 +358,26 @@ class DesignRefRule(Rule):
 
 
 # patterns that must never be tracked, and must be gitignored
-_GITIGNORE_REQUIRED = ("__pycache__/", "*.pyc", "experiments/runs/")
+_SCRATCH_DIRS = ("experiments/runs/", ".hypothesis/", ".jax_cache/")
+_GITIGNORE_REQUIRED = ("__pycache__/", "*.pyc") + _SCRATCH_DIRS
 
 
 def _is_scratch(path: str) -> bool:
     parts = path.split("/")
     return ("__pycache__" in parts or path.endswith(".pyc")
-            or path.startswith("experiments/runs/"))
+            or path.startswith(_SCRATCH_DIRS))
 
 
 @register
 class RepoHygieneRule(Rule):
-    """Bytecode caches and run-ledger scratch are machine-local; a
-    tracked copy goes stale immediately and churns every diff."""
+    """Bytecode caches, run-ledger scratch, hypothesis's example database
+    and the XLA compilation cache are machine-local; a tracked copy goes
+    stale immediately and churns every diff."""
     name = "repo-hygiene"
     severity = "error"
-    description = ("no __pycache__/*.pyc/experiments/runs/ scratch is "
-                   "git-tracked, and .gitignore covers those patterns")
+    description = ("no __pycache__/*.pyc/experiments/runs/.hypothesis/"
+                   ".jax_cache/ scratch is git-tracked, and .gitignore "
+                   "covers those patterns")
 
     def check_repo(self, ctx: RepoContext) -> Iterable[Finding]:
         if ctx.tracked_files is not None:
