@@ -864,15 +864,22 @@ def _fast_schedule_batch(priority, gains, t_cmp, n_samples, model_bits,
     wasteful — DESIGN.md section 13)."""
     seg = admission == "segmented"
     admit = _admit_fast_seg if seg else _admit_fast
-    cand = admit(priority, gains, n_cand0)
-    out = _fast_finish(cand, gains, t_cmp, n_samples, model_bits, prm, oma,
-                       n_pairs, n_cand0, pairing_policy, impl)
+    # named scopes: each op's name-scope path in the device trace names
+    # the stage it belongs to (admission vs pairing/power/round time)
+    with jax.named_scope("mc.admit"):
+        cand = admit(priority, gains, n_cand0)
+    with jax.named_scope("mc.finish"):
+        out = _fast_finish(cand, gains, t_cmp, n_samples, model_bits, prm,
+                           oma, n_pairs, n_cand0, pairing_policy, impl)
     if selection == "joint" and 0 < n_cand0 < gains.shape[-1]:
-        refined = _joint_refine_mask(cand, gains, t_cmp, model_bits, prm,
-                                     oma, n_cand0, segmented=seg)
-        out = _pick_faster(
-            _fast_finish(refined, gains, t_cmp, n_samples, model_bits, prm,
-                         oma, n_pairs, n_cand0, pairing_policy, impl), out)
+        with jax.named_scope("mc.admit"):
+            refined = _joint_refine_mask(cand, gains, t_cmp, model_bits,
+                                         prm, oma, n_cand0, segmented=seg)
+        with jax.named_scope("mc.finish"):
+            out = _pick_faster(
+                _fast_finish(refined, gains, t_cmp, n_samples, model_bits,
+                             prm, oma, n_pairs, n_cand0, pairing_policy,
+                             impl), out)
     return out
 
 
@@ -1825,7 +1832,8 @@ class WirelessEngine:
         prev_cell = None
         t_rounds, n_sels, max_ages, handovers = [], [], [], []
         t_comp_bs, t_up_bs, n_evs, aou_hists = [], [], [], []
-        mc_span = trace.span("engine.mc_loop", rounds=rounds, policy=policy)
+        mc_span = trace.span("engine.mc_loop", rounds=rounds, policy=policy,
+                             seed=seed)
         with mc_span as sp:
             for i in range(rounds):
                 gains, n_samples, cpu_freq, cellv = env_fn(i)
@@ -1916,22 +1924,26 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
     are then the per-cell values for capacity ``cap``). ``impl`` routes
     the fast paths' scoring in-kernel; the budget path rescores post-hoc
     (see ``_rescore_pallas``). With a ``mesh`` the priorities are drawn
-    over the whole seed axis and each device plans its own seeds."""
+    over the whole seed axis and each device plans its own seeds. The
+    stages are named scopes (``mc.priority``, ``mc.admit``,
+    ``mc.finish``, ``mc.ages``), so each op in a device trace carries the
+    stage it belongs to."""
     s, n = gains.shape
     oma = policy == "oma_age"
-    t_cmp = _compute_times(prm, n_samples, cpu_freq)
-    mb = jnp.broadcast_to(model_bits, (s,))
-    if policy in ("age_noma", "age_noma_budget", "oma_age"):
-        prio = _age_priority(ages, n_samples, gains, gamma)
-    elif policy == "channel":
-        prio = gains
-    elif policy == "random":
-        prio = jax.random.uniform(key, gains.shape)
-    elif policy == "round_robin":
-        prio = jnp.broadcast_to(round_robin_priority(round_idx, n, n_cand0),
-                                gains.shape)
-    else:
-        raise ValueError(f"unknown montecarlo policy {policy!r}")
+    with jax.named_scope("mc.priority"):
+        t_cmp = _compute_times(prm, n_samples, cpu_freq)
+        mb = jnp.broadcast_to(model_bits, (s,))
+        if policy in ("age_noma", "age_noma_budget", "oma_age"):
+            prio = _age_priority(ages, n_samples, gains, gamma)
+        elif policy == "channel":
+            prio = gains
+        elif policy == "random":
+            prio = jax.random.uniform(key, gains.shape)
+        elif policy == "round_robin":
+            prio = jnp.broadcast_to(
+                round_robin_priority(round_idx, n, n_cand0), gains.shape)
+        else:
+            raise ValueError(f"unknown montecarlo policy {policy!r}")
 
     def plan(prio, gains, t_cmp, n_samples, mb, cell):
         rows = gains.shape[0]
@@ -1968,12 +1980,14 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
 
     sched = _per_shard(plan, mesh, prio, gains, t_cmp, n_samples, mb,
                        cell if n_cells > 1 else None)
-    sel = sched.selected
-    ages2 = jnp.where(sel, 1.0, ages + 1.0)
-    diag = schedule_diag(sched, ages2)
-    return (ages2, part + sel, sched.t_round, jnp.sum(sel, axis=1),
-            jnp.max(ages2, axis=1), diag["t_comp_bottleneck"],
-            diag["t_up_bottleneck"], diag["n_evicted"], diag["aou_hist"])
+    with jax.named_scope("mc.ages"):
+        sel = sched.selected
+        ages2 = jnp.where(sel, 1.0, ages + 1.0)
+        diag = schedule_diag(sched, ages2)
+        return (ages2, part + sel, sched.t_round, jnp.sum(sel, axis=1),
+                jnp.max(ages2, axis=1), diag["t_comp_bottleneck"],
+                diag["t_up_bottleneck"], diag["n_evicted"],
+                diag["aou_hist"])
 
 
 def engine_schedule_to_numpy(out: EngineSchedule, b: int,

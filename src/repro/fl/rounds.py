@@ -14,7 +14,7 @@ from repro.configs.base import (  # noqa: F401  (POLICIES re-export)
 )
 from repro.data import TaskConfig
 from repro.fl.server import FLServer, History
-from repro.obs import RunLedger
+from repro.obs import RunLedger, trace
 
 # the Monte-Carlo driver covers every FLServer policy (engine-side
 # round_robin/random priorities + budget auto-calibration); the old
@@ -114,95 +114,106 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
 
     nomacfg = nomacfg or NOMAConfig()
     flcfg = flcfg or FLConfig()
-    # subchannel pairing policy + admitted-set selection mode + admission
-    # implementation: every POLICY x scenario sweep can run any (pairing,
-    # selection, admission) combination (core/pairing.py, core/plan.py;
-    # threaded through the fused MC step — an unknown admission value
-    # raises in the engine constructor, never a silent fallback)
-    eng = WirelessEngine(nomacfg, flcfg, kernel_backend=kernel_backend,
-                         use_pallas=use_pallas, pairing=pairing,
-                         selection=selection, admission=admission)
-    scn = as_scenario(scenario, nomacfg, flcfg)
     s, n, r = n_seeds, n_clients, rounds
-    k_env = jax.random.PRNGKey(seed)
+    with trace.span("mc.call", seed=seed, drops=s * r):
+        with trace.span("mc.setup", seed=seed):
+            # subchannel pairing policy + admitted-set selection mode +
+            # admission implementation: every POLICY x scenario sweep can
+            # run any (pairing, selection, admission) combination
+            # (core/pairing.py, core/plan.py; threaded through the fused MC
+            # step — an unknown admission value raises in the engine
+            # constructor, never a silent fallback)
+            eng = WirelessEngine(nomacfg, flcfg,
+                                 kernel_backend=kernel_backend,
+                                 use_pallas=use_pallas, pairing=pairing,
+                                 selection=selection, admission=admission)
+            scn = as_scenario(scenario, nomacfg, flcfg)
+            k_env = jax.random.PRNGKey(seed)
 
-    multicell = flcfg.n_cells > 1
-    envs = scn.rollout(k_env, r, (s, n)) if presampled else None
-    auto_budget = None
-    if "age_noma_budget" in policies and t_budget <= 0.0:
-        # first_env deliberately replays round 0 of rollout's key
-        # schedule so the budget calibration sees the same draws
-        env0 = (tuple(a[0] for a in envs) if envs is not None
-                else scn.first_env(k_env, r, (s, n)))  # reprolint: disable=key-reuse
-        ref = eng.schedule_batch(env0[0], env0[1], env0[2],
-                                 jnp.ones((s, n), jnp.float32), model_bits,
-                                 priority=env0[0],
-                                 cell=env0[3] if multicell else None)
-        auto_budget = 2.0 * max(float(np.asarray(ref.t_round).mean()), 1e-6)
+            multicell = flcfg.n_cells > 1
+            envs = scn.rollout(k_env, r, (s, n)) if presampled else None
+            auto_budget = None
+            if "age_noma_budget" in policies and t_budget <= 0.0:
+                # first_env deliberately replays round 0 of rollout's key
+                # schedule so the budget calibration sees the same draws
+                env0 = (tuple(a[0] for a in envs) if envs is not None
+                        else scn.first_env(k_env, r, (s, n)))  # reprolint: disable=key-reuse
+                ref = eng.schedule_batch(
+                    env0[0], env0[1], env0[2], jnp.ones((s, n), jnp.float32),
+                    model_bits, priority=env0[0],
+                    cell=env0[3] if multicell else None)
+                auto_budget = 2.0 * max(
+                    float(np.asarray(ref.t_round).mean()), 1e-6)
 
-    results: dict = {"summary": {}, "meta": {
-        "n_clients": n, "n_seeds": s, "rounds": r,
-        "model_bits": model_bits, "t_budget": t_budget,
-        "scenario": scn.name, "presampled": bool(presampled),
-        "slots": eng.prm.slots, "use_pallas": use_pallas,
-        "kernel_backend": eng.kernel_backend,
-        "kernel_impl": eng.impl,
-        "pairing": eng.pairing, "selection": eng.selection,
-        "admission": eng.admission,
-        "n_cells": flcfg.n_cells, "cell_layout": flcfg.cell_layout}}
-    ledger = RunLedger.open("montecarlo", {
-        **results["meta"], "policies": list(policies), "seed": seed})
-    try:
-        for policy in policies:
-            tb = t_budget
-            if policy == "age_noma_budget" and tb <= 0.0:
-                tb = auto_budget
-            if envs is not None:
-                out = eng.montecarlo_rounds(
-                    np.asarray(envs.gains), np.asarray(envs.n_samples),
-                    np.asarray(envs.cpu_freq), model_bits, policy=policy,
-                    t_budget=tb, seed=seed, shard=shard,
-                    cell_seq=np.asarray(envs.cell) if multicell else None)
-            else:
-                out = eng.montecarlo_scenario(
-                    scn, rounds=r, n_seeds=s, n_clients=n,
-                    model_bits=model_bits, policy=policy, t_budget=tb,
-                    seed=seed, key=k_env, shard=shard)
-            t_round = np.asarray(out["t_round"])          # (R, S)
-            part = np.asarray(out["participation"])       # (S, N)
-            jain = (part.sum(1) ** 2
-                    / np.maximum(n * (part ** 2).sum(1), 1e-12))  # (S,)
-            results[policy] = {k: np.asarray(v) for k, v in out.items()}
-            # every policy emits the SAME summary key set (None when
-            # inapplicable) so cross-policy/config diffs never KeyError
-            results["summary"][policy] = {
-                "mean_t_round_s": float(t_round.mean()),
-                "total_time_s": float(t_round.sum(0).mean()),
-                "max_age": int(np.asarray(out["max_age"]).max()),
-                "mean_max_age": float(np.asarray(out["max_age"]).mean()),
-                "jain_participation": float(jain.mean()),
-                # round-time decomposition of the bottleneck pair
-                # (means sum to mean_t_round_s within fp32 tolerance)
-                "mean_t_comp_bottleneck_s": float(
-                    np.asarray(out["t_comp_bottleneck"]).mean()),
-                "mean_t_up_bottleneck_s": float(
-                    np.asarray(out["t_up_bottleneck"]).mean()),
-                "mean_n_evicted": float(
-                    np.asarray(out["n_evicted"]).mean()),
-                # population AoU histogram summed over rounds x seeds
-                # ((7,) counts on metrics.AOU_BUCKET_EDGES)
-                "aou_hist": np.asarray(out["aou_hist"])
-                .sum(axis=(0, 1)).tolist(),
-                "handover_rate": (
-                    float(np.asarray(out["handovers"]).mean() / n)
-                    if "handovers" in out else None),
-                "t_budget_s": (float(tb) if policy == "age_noma_budget"
-                               else None),
-            }
-            ledger.event("policy_done", policy=policy,
-                         summary=results["summary"][policy])
-    finally:
-        ledger.close()
+            results: dict = {"summary": {}, "meta": {
+                "n_clients": n, "n_seeds": s, "rounds": r,
+                "model_bits": model_bits, "t_budget": t_budget,
+                "scenario": scn.name, "presampled": bool(presampled),
+                "slots": eng.prm.slots, "use_pallas": use_pallas,
+                "kernel_backend": eng.kernel_backend,
+                "kernel_impl": eng.impl,
+                "pairing": eng.pairing, "selection": eng.selection,
+                "admission": eng.admission,
+                "n_cells": flcfg.n_cells, "cell_layout": flcfg.cell_layout}}
+            ledger = RunLedger.open("montecarlo", {
+                **results["meta"], "policies": list(policies), "seed": seed})
+        try:
+            for policy in policies:
+                tb = t_budget
+                if policy == "age_noma_budget" and tb <= 0.0:
+                    tb = auto_budget
+                if envs is not None:
+                    out = eng.montecarlo_rounds(
+                        np.asarray(envs.gains), np.asarray(envs.n_samples),
+                        np.asarray(envs.cpu_freq), model_bits,
+                        policy=policy, t_budget=tb, seed=seed, shard=shard,
+                        cell_seq=np.asarray(envs.cell) if multicell
+                        else None)
+                else:
+                    out = eng.montecarlo_scenario(
+                        scn, rounds=r, n_seeds=s, n_clients=n,
+                        model_bits=model_bits, policy=policy, t_budget=tb,
+                        seed=seed, key=k_env, shard=shard)
+                with trace.span("mc.collect", seed=seed) as sp:
+                    t_round = np.asarray(out["t_round"])          # (R, S)
+                    part = np.asarray(out["participation"])       # (S, N)
+                    jain = (part.sum(1) ** 2                      # (S,)
+                            / np.maximum(n * (part ** 2).sum(1), 1e-12))
+                    results[policy] = {k: np.asarray(v)
+                                       for k, v in out.items()}
+                    sp.note(bytes=sum(v.nbytes for v in
+                                      results[policy].values()))
+                    # every policy emits the SAME summary key set (None when
+                    # inapplicable) so cross-policy/config diffs never KeyError
+                    results["summary"][policy] = {
+                        "mean_t_round_s": float(t_round.mean()),
+                        "total_time_s": float(t_round.sum(0).mean()),
+                        "max_age": int(np.asarray(out["max_age"]).max()),
+                        "mean_max_age": float(
+                            np.asarray(out["max_age"]).mean()),
+                        "jain_participation": float(jain.mean()),
+                        # round-time decomposition of the bottleneck pair
+                        # (means sum to mean_t_round_s within fp32 tolerance)
+                        "mean_t_comp_bottleneck_s": float(
+                            np.asarray(out["t_comp_bottleneck"]).mean()),
+                        "mean_t_up_bottleneck_s": float(
+                            np.asarray(out["t_up_bottleneck"]).mean()),
+                        "mean_n_evicted": float(
+                            np.asarray(out["n_evicted"]).mean()),
+                        # population AoU histogram summed over rounds x seeds
+                        # ((7,) counts on metrics.AOU_BUCKET_EDGES)
+                        "aou_hist": np.asarray(out["aou_hist"])
+                        .sum(axis=(0, 1)).tolist(),
+                        "handover_rate": (
+                            float(np.asarray(out["handovers"]).mean() / n)
+                            if "handovers" in out else None),
+                        "t_budget_s": (float(tb) if policy == "age_noma_budget"
+                                       else None),
+                    }
+                    ledger.event("policy_done", policy=policy,
+                                 summary=results["summary"][policy])
+        finally:
+            ledger.close()
     return results
 
 

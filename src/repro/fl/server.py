@@ -310,43 +310,70 @@ class FLServer:
                                    t_budget=t_budget or None,  # 0.0 => none
                                    info={"policy": p, "engine": "numpy"})
     def run_round(self) -> Schedule:
-        # advance the wireless environment; under dynamic scenarios the
-        # env's n_samples only shape the SCHEDULER's view (age priority
-        # weighting + T_cmp) — local batches and aggregation weights stay
-        # tied to the fixed client datasets, so real and predicted deltas
-        # share one weight convention
-        gains, env_n_samples, env_cpu = self.scenario.step(self.rng)
-        env = RoundEnv(gains=gains, n_samples=env_n_samples,
-                       cpu_freq=env_cpu, ages=self.ages,
-                       model_bits=self.model_bits)
-        sched = self.select(env)
+        """One round, spanned at each phase (``obs.trace``, every span with
+        ``r`` = the round index): ``server.round`` over
+        ``server.scenario``, ``server.select``, ``server.train`` (one
+        ``client.update`` per client; counts ``clients`` and ``steps``, the
+        SGD steps dispatched) and ``server.aggregate`` (counts ``clients``
+        and ``bytes``, the deltas' logical bytes; fenced on the new
+        parameters), ``server.predict`` inside it under the predictor."""
+        r = self.round_idx
+        with trace.span("server.round", r=r):
+            # advance the wireless environment; under dynamic scenarios the
+            # env's n_samples only shape the SCHEDULER's view (age priority
+            # weighting + T_cmp) — local batches and aggregation weights
+            # stay tied to the fixed client datasets, so real and predicted
+            # deltas share one weight convention
+            with trace.span("server.scenario", r=r):
+                gains, env_n_samples, env_cpu = self.scenario.step(self.rng)
+                env = RoundEnv(gains=gains, n_samples=env_n_samples,
+                               cpu_freq=env_cpu, ages=self.ages,
+                               model_bits=self.model_bits)
+            with trace.span("server.select", r=r):
+                sched = self.select(env)
 
-        sel = np.flatnonzero(sched.selected)
-        deltas, weights = [], []
-        for ci in sel:
-            batches = client_batches(self.rng, self.clients[ci],
-                                     self.fl.local_batch,
-                                     self.fl.local_epochs)
-            delta, _ = self.trainer.local_update(self.params, batches)
-            deltas.append(delta)
-            weights.append(self.n_samples[ci])
-        self.pred_stats = {"n_predicted": 0, "pred_loss": float("nan"),
-                           "pred_error": float("nan")}
-        if deltas and self.predictor is None:
-            agg = aggregate_deltas(deltas, np.asarray(weights),
-                                   impl=self.agg_impl)
-            self.params = apply_aggregate(self.params, agg)
-        elif deltas:
-            self._aggregate_with_predictions(sel, deltas, weights)
+            sel = np.flatnonzero(sched.selected)
+            deltas, weights = [], []
+            with trace.span("server.train", r=r, clients=len(sel)) as sp:
+                steps = 0
+                for ci in sel:
+                    with trace.span("client.update", r=r,
+                                    client=int(ci)) as cu:
+                        batches = list(client_batches(
+                            self.rng, self.clients[ci], self.fl.local_batch,
+                            self.fl.local_epochs))
+                        cu.note(steps=len(batches))
+                        delta, _ = self.trainer.local_update(self.params,
+                                                             batches)
+                    steps += len(batches)
+                    deltas.append(delta)
+                    weights.append(self.n_samples[ci])
+                sp.note(steps=steps)
+            self.pred_stats = {"n_predicted": 0, "pred_loss": float("nan"),
+                               "pred_error": float("nan")}
+            if deltas:
+                nbytes = len(deltas) * sum(
+                    x.nbytes for x in jax.tree.leaves(deltas[0]))
+                with trace.span("server.aggregate", r=r,
+                                clients=len(deltas), bytes=nbytes) as sp:
+                    if self.predictor is None:
+                        agg = aggregate_deltas(deltas, np.asarray(weights),
+                                               impl=self.agg_impl)
+                    else:
+                        with trace.span("server.predict", r=r):
+                            agg = self._aggregate_with_predictions(
+                                sel, deltas, weights)
+                    self.params = apply_aggregate(self.params, agg)
+                    sp.fence(self.params)
 
-        self.ages = aoi.update_ages(self.ages, sched.selected)
-        self.t_sim += sched.t_round
-        self.round_idx += 1
+            self.ages = aoi.update_ages(self.ages, sched.selected)
+            self.t_sim += sched.t_round
+            self.round_idx += 1
         return sched
 
     def _aggregate_with_predictions(self, sel, deltas, weights):
         """Predictor path: train on arrivals, predict the unselected, blend
-        with age-discounted weights, apply."""
+        with age-discounted weights -> the aggregate delta."""
         pred = self.predictor
         data_w = self.n_samples / self.n_samples.sum()
         flat = [pred.flatten(d) for d in deltas]
@@ -363,10 +390,9 @@ class FLServer:
         w_pred = (self.n_samples[targets] * self.fl.pred_blend
                   * aoi.age_discount(self.ages[targets],
                                      self.fl.pred_discount))
-        agg = blend_deltas(deltas, w_real, pred_trees, w_pred,
-                           impl=self.agg_impl)
-        self.params = apply_aggregate(self.params, agg)
         self.pred_stats = {"n_predicted": len(targets), **stats}
+        return blend_deltas(deltas, w_real, pred_trees, w_pred,
+                            impl=self.agg_impl)
 
     # -- full experiment ---------------------------------------------------
     def run(self, rounds: Optional[int] = None, *, verbose: bool = False,
@@ -393,8 +419,7 @@ class FLServer:
             else None
         try:
             for r in range(rounds):
-                with trace.span("server.round", r=r):
-                    sched = self.run_round()
+                sched = self.run_round()
                 part += sched.selected
                 if r % self.eval_every == 0 or r == rounds - 1:
                     acc, loss = self.evaluate()

@@ -73,5 +73,6 @@ def fedagg_pallas(updates, weights, *, block_n: int = BLOCK_N,
         out_specs=pl.BlockSpec((1, bn), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="fedagg",
     )(weights.astype(jnp.float32).reshape(c, 1), updates)
     return out[0]

@@ -119,6 +119,7 @@ def pairscore_pallas(g_i, g_j, *, n0b: float, pmax: float, bw: float,
         out_specs=(spec, spec, spec, spec),
         out_shape=(out_sds, out_sds, out_sds, out_sds),
         interpret=interpret,
+        name="pairscore",
     )(gi2, gj2)
     return tuple(o.reshape(-1)[:size].reshape(shape) for o in outs)
 

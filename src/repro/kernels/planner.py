@@ -140,6 +140,7 @@ def planner_tables_pallas(g_sorted, t_cmp_sorted, model_bits, *,
                    jax.ShapeDtypeStruct((b, cp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((b, 1, LANES), jnp.float32)),
         interpret=interpret,
+        name="planner_tables",
     )(g2[:, :, None], t2[:, :, None], g2[:, None, :], t2[:, None, :],
       jnp.broadcast_to(mb.reshape(b, 1, 1), (b, 1, LANES)))
     table = tab[:, :c, :c].reshape(lead + (c, c))

@@ -1,15 +1,10 @@
-"""Round metrics: counters/gauges/histograms + the shared diag-leaf
-definitions both engines implement (DESIGN.md section 11).
+"""Round metrics: the shared diag-leaf definitions both engines implement
+(DESIGN.md section 11).
 
-Two layers:
-
-* ``MetricsRegistry`` — a tiny host-side counters/gauges/histograms
-  registry the drivers fold per-round telemetry into (``as_dict()`` is
-  JSON-safe and feeds the run ledger).
-* shared diag constants — the AoU histogram bucket edges
-  (``AOU_BUCKET_EDGES``) and the numpy bucketizer (``aou_histogram``)
-  whose jax twin lives in ``core/engine.py`` (``engine.schedule_diag``),
-  kept here so the two bucketings can never disagree.
+The AoU histogram bucket edges (``AOU_BUCKET_EDGES``) and the numpy
+bucketizer (``aou_histogram``) whose jax twin lives in ``core/engine.py``
+(``engine.schedule_diag``), kept here so the two bucketings can never
+disagree.
 
 ``json_safe`` is the ONE non-finite/ndarray scrubbing rule shared by
 ``History.as_dict``, the MC summaries, and the JSONL ledger: ndarrays
@@ -19,14 +14,11 @@ become ``None`` (bare NaN tokens break strict JSON parsers).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+from typing import Sequence
 
 import numpy as np
 
-__all__ = [
-    "AOU_BUCKET_EDGES", "aou_histogram", "json_safe",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
-]
+__all__ = ["AOU_BUCKET_EDGES", "aou_histogram", "json_safe"]
 
 # AoU histogram bucket upper edges (ages are integers >= 1): bucket i
 # counts ages in (edge[i-1], edge[i]], the last bucket counts > edge[-1].
@@ -74,101 +66,3 @@ def json_safe(v):
         return v
     return str(v)
 
-
-# ---------------------------------------------------------------------------
-# registry
-# ---------------------------------------------------------------------------
-
-
-class Counter:
-    """Monotone event count."""
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = 0
-
-    def inc(self, k: int = 1) -> None:
-        self.value += k
-
-    def as_dict(self):
-        return {"type": "counter", "value": self.value}
-
-
-class Gauge:
-    """Last-write-wins scalar."""
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value: Optional[float] = None
-
-    def set(self, v) -> None:
-        self.value = float(v)
-
-    def as_dict(self):
-        return {"type": "gauge", "value": json_safe(self.value)}
-
-
-class Histogram:
-    """Fixed-bucket histogram (same edge semantics as ``aou_histogram``:
-    bucket i is (edges[i-1], edges[i]], last bucket > edges[-1])."""
-    __slots__ = ("edges", "counts", "total", "sum")
-
-    def __init__(self, edges: Sequence[float]):
-        self.edges = tuple(float(e) for e in edges)
-        self.counts = np.zeros(len(self.edges) + 1, dtype=np.int64)
-        self.total = 0
-        self.sum = 0.0
-
-    def observe(self, v) -> None:
-        self.observe_many(np.asarray([v], dtype=np.float64))
-
-    def observe_many(self, values) -> None:
-        values = np.asarray(values, dtype=np.float64).ravel()
-        idx = np.searchsorted(np.asarray(self.edges), values, side="left")
-        self.counts += np.bincount(idx, minlength=len(self.counts)
-                                   ).astype(np.int64)
-        self.total += values.size
-        self.sum += float(values.sum())
-
-    def as_dict(self):
-        return {"type": "histogram", "edges": list(self.edges),
-                "counts": self.counts.tolist(), "total": self.total,
-                "sum": json_safe(self.sum)}
-
-
-class MetricsRegistry:
-    """Name -> instrument registry (get-or-create accessors). One registry
-    per run/driver; ``as_dict()`` snapshots everything JSON-safe for the
-    ledger. Re-registering a histogram name with different edges raises —
-    silently merging incompatible buckets corrupts counts."""
-
-    def __init__(self):
-        self._items: dict = {}
-
-    def counter(self, name: str) -> Counter:
-        return self._get(name, Counter, lambda: Counter())
-
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge, lambda: Gauge())
-
-    def histogram(self, name: str,
-                  edges: Sequence[float] = AOU_BUCKET_EDGES) -> Histogram:
-        h = self._get(name, Histogram, lambda: Histogram(edges))
-        if h.edges != tuple(float(e) for e in edges):
-            raise ValueError(
-                f"histogram {name!r} already registered with edges "
-                f"{h.edges}, got {tuple(edges)}")
-        return h
-
-    def _get(self, name, cls, make):
-        item = self._items.get(name)
-        if item is None:
-            item = self._items[name] = make()
-        elif not isinstance(item, cls):
-            raise ValueError(f"metric {name!r} is a "
-                             f"{type(item).__name__}, not a {cls.__name__}")
-        return item
-
-    def as_dict(self) -> dict:
-        return {name: item.as_dict()
-                for name, item in sorted(self._items.items())}

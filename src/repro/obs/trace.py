@@ -3,7 +3,7 @@
 The observability layer's timing substrate (DESIGN.md section 11). A
 ``Span`` is one timed region of host code — a planner stage, an engine
 dispatch, a benchmark rep — recorded on a monotonic clock
-(``time.perf_counter``) with explicit nesting. Three contracts matter for
+(``time.perf_counter``) with explicit nesting. Four contracts matter for
 JAX code:
 
 * **fencing** — an XLA dispatch returns before the computation finishes,
@@ -14,11 +14,17 @@ JAX code:
   pays tracing + XLA compilation on top of execution. Spans carry a
   ``cold`` flag (``Tracer.cold(key)`` marks the first sighting of a
   static signature) so reports can separate amortized-away compile time
-  from steady-state execution; ``compile_split`` performs the exact AOT
-  split (lower / compile / execute timed separately) for one entry point.
+  from steady-state execution.
+* **one clock** — an enabled span also enters
+  ``jax.profiler.TraceAnnotation(name, **meta)``: under a running
+  ``jax.profiler`` trace every span is a host-plane event on the same
+  clock as the device's operations, its scalar meta (and late
+  ``note(...)`` values) as the event's arguments, tuples as strings.
+  With no profiler running the annotation costs about a microsecond.
 * **zero cost when disabled** — the global tracer is OFF by default and
   the disabled ``span`` is a shared no-op context (no generator, no
-  allocation), so production paths keep their instrumentation permanently.
+  allocation, no ``jax`` import), so production paths keep their
+  instrumentation permanently.
 
 Usage::
 
@@ -31,18 +37,21 @@ Usage::
 
 ``profile(outdir)`` is the opt-in ``jax.profiler.trace`` hook (surfaced
 through ``launch/perf.py --profile``) for when host spans are not enough
-and the full XLA timeline is needed.
+and the full XLA timeline is needed; it raises when the profiler cannot
+start.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import numbers
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 __all__ = [
     "Span", "Tracer", "tracing", "span", "get_tracer", "set_tracer",
-    "compile_split", "profile", "summarize", "format_report",
+    "profile", "summarize", "format_report",
 ]
 
 
@@ -63,11 +72,12 @@ class Span:
 
 class _Handle:
     """The object a live ``span(...)`` yields: attach fences + metadata."""
-    __slots__ = ("_fences", "meta")
+    __slots__ = ("_fences", "meta", "_late")
 
     def __init__(self, meta: dict):
         self._fences: list = []
         self.meta = meta
+        self._late: dict = {}     # notes made after entry
 
     def fence(self, *arrays) -> None:
         """Register arrays/pytrees to ``jax.block_until_ready`` at exit."""
@@ -75,6 +85,16 @@ class _Handle:
 
     def note(self, **meta) -> None:
         self.meta.update(meta)
+        self._late.update(meta)
+
+
+def _event_args(meta: dict) -> dict:
+    """A span's meta as profiler event arguments: real numbers and strings
+    as they are, tuples as strings; ``cold`` and other values are left
+    out."""
+    return {k: str(v) if isinstance(v, tuple) else v
+            for k, v in meta.items()
+            if k != "cold" and isinstance(v, (numbers.Real, str, tuple))}
 
 
 class _NullHandle:
@@ -105,10 +125,18 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
+@functools.lru_cache(maxsize=None)
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first enabled
+    span (a disabled tracer never imports ``jax``)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
 class _SpanCtx:
     """Live span context manager (plain class — cheaper than a
     ``@contextmanager`` generator on hot paths)."""
-    __slots__ = ("_tracer", "_name", "_cold", "_handle", "_t0")
+    __slots__ = ("_tracer", "_name", "_cold", "_handle", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cold: bool, meta: dict):
         self._tracer = tracer
@@ -118,6 +146,9 @@ class _SpanCtx:
 
     def __enter__(self):
         self._tracer._stack.append(self._name)
+        self._ann = _annotation()(self._name,
+                                  **_event_args(self._handle.meta))
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self._handle
 
@@ -127,6 +158,10 @@ class _SpanCtx:
             import jax
             jax.block_until_ready(h._fences)
         dt = time.perf_counter() - self._t0
+        late = _event_args(h._late)
+        if late:
+            self._ann.set_metadata(**late)
+        self._ann.__exit__(*exc)
         tr = self._tracer
         tr._stack.pop()
         depth = len(tr._stack)
@@ -211,42 +246,18 @@ def tracing(enabled: bool = True):
         set_tracer(old)
 
 
-# -- compile-vs-execute ------------------------------------------------------
-
-
-def compile_split(fn: Callable, *args, **kwargs) -> tuple:
-    """AOT-split one jitted entry point: returns
-    ``(out, {"trace_s", "compile_s", "execute_s"})`` with the three phases
-    timed separately (``fn`` must be a ``jax.jit``-wrapped callable; the
-    execute phase is fenced). This is the exact split; the spans' ``cold``
-    flag is the cheap in-band approximation for entry points that cannot
-    be AOT-compiled (e.g. facades dispatching to several cores)."""
-    import jax
-
-    t0 = time.perf_counter()
-    lowered = fn.lower(*args, **kwargs)
-    t1 = time.perf_counter()
-    compiled = lowered.compile()
-    t2 = time.perf_counter()
-    out = compiled(*args, **kwargs)
-    jax.block_until_ready(out)
-    t3 = time.perf_counter()
-    return out, {"trace_s": t1 - t0, "compile_s": t2 - t1,
-                 "execute_s": t3 - t2}
+# -- profiler ------------------------------------------------------------
 
 
 @contextlib.contextmanager
 def profile(outdir: str):
     """Opt-in ``jax.profiler.trace`` hook: dump an XLA/TensorBoard profile
     of the block to ``outdir`` (view with ``tensorboard --logdir``).
-    Degrades to a no-op if the profiler is unavailable on this backend."""
+    Raises what the profiler raises when it cannot start (one already
+    running, no profiler on this backend)."""
     import jax
 
-    try:
-        ctx = jax.profiler.trace(outdir)
-    except Exception:  # pragma: no cover - profiler not available
-        ctx = contextlib.nullcontext()
-    with ctx:
+    with jax.profiler.trace(outdir):
         yield
 
 

@@ -7,6 +7,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -17,7 +18,6 @@ from repro.core.engine import schedule_diag as engine_schedule_diag
 from repro.fl.server import History
 from repro.obs import (
     AOU_BUCKET_EDGES,
-    MetricsRegistry,
     RunLedger,
     aou_histogram,
     json_safe,
@@ -48,13 +48,160 @@ def test_span_nesting_and_parent():
 
 def test_span_disabled_is_noop():
     # outside a tracing() block the global tracer is disabled: spans
-    # record nothing and cold() always says False
+    # record nothing, every span is one shared no-op object, and cold()
+    # always says False
     before = list(trace.get_tracer().spans)
     with trace.span("nope") as h:
         h.note(x=1)
         h.fence(np.zeros(3))
     assert list(trace.get_tracer().spans) == before
+    assert trace.span("a", r=1) is trace.span("b")
     assert trace.cold(("some", "key")) is False
+
+
+def test_disabled_span_imports_no_jax():
+    code = ("import sys\nfrom repro.obs import trace\n"
+            "with trace.span('x', r=1) as h:\n    h.note(y=2)\n"
+            "print(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules))")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=60, env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")})
+    assert out.stdout.strip() == "False", out.stderr
+
+
+def _host_events(tmp_path, body) -> dict:
+    """name -> [arguments] of the host-plane events of a ``jax.profiler``
+    trace of ``body()``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(dict(e.stats))
+    return out
+
+
+def test_enabled_span_is_a_profiler_host_event(tmp_path):
+    # scalar meta become the event's arguments, tuples strings, late notes
+    # are added at exit; arrays and the cold flag are left out
+    def body():
+        with trace.tracing():
+            with trace.span("obs.outer", r=3, shape=(2, 3), arr=np.zeros(2)):
+                with trace.span("obs.inner", cold=False) as sp:
+                    sp.note(steps=7, cold=True)
+        with trace.span("obs.off", r=1):
+            pass
+    ev = _host_events(tmp_path, body)
+    assert ev["obs.outer"] == [{"r": 3, "shape": "(2, 3)"}]
+    assert ev["obs.inner"] == [{"steps": 7}]
+    assert "obs.off" not in ev
+
+
+def test_profile_raises_when_the_profiler_cannot_start(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path / "a"))
+    try:
+        with pytest.raises(RuntimeError):
+            with trace.profile(str(tmp_path / "b")):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("predictor", ["none", "stale"])
+def test_run_round_spans_each_phase(predictor):
+    import collections
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.data import TaskConfig
+    from repro.fl import FLServer
+    cfg = dataclasses.replace(get_config("smollm_135m").reduced(),
+                              d_model=32, d_ff=64, vocab_size=32, n_layers=2)
+    srv = FLServer(cfg, FLConfig(n_clients=8, local_batch=8, lr=0.2,
+                                 samples_per_client=(24, 48), seed=0),
+                   NOMAConfig(n_subchannels=2),
+                   TaskConfig(vocab_size=32, n_topics=4, seq_len=17, seed=0),
+                   predictor=predictor)
+    srv.run_round()
+    real_step, calls = srv.trainer.step, []
+
+    def step(*args):
+        calls.append(1)
+        return real_step(*args)
+
+    srv.trainer.step = step
+    with trace.tracing() as tr:
+        srv.run_round()
+    by = collections.defaultdict(list)
+    for s in tr.spans:
+        by[s.name].append(s)
+    assert all(s.meta["r"] == 1 for name, ss in by.items()
+               if name.startswith(("server.", "client.")) for s in ss)
+    (rnd,) = by["server.round"]
+    assert rnd.parent is None
+    for name in ("server.scenario", "server.select", "server.train",
+                 "server.aggregate"):
+        (s,) = by[name]
+        assert s.parent == "server.round", name
+    (train,), (agg,) = by["server.train"], by["server.aggregate"]
+    updates = by["client.update"]
+    assert updates and all(u.parent == "server.train" for u in updates)
+    assert train.meta["clients"] == len(updates) == agg.meta["clients"]
+    assert train.meta["steps"] == len(calls) == sum(u.meta["steps"]
+                                                    for u in updates) > 0
+    leaf_bytes = sum(x.nbytes for x in jax.tree.leaves(srv.params))
+    assert agg.meta["bytes"] == len(updates) * leaf_bytes
+    if predictor == "none":
+        assert "server.predict" not in by
+    else:
+        (pred,) = by["server.predict"]
+        assert pred.parent == "server.aggregate"
+
+
+def test_run_montecarlo_spans_each_phase():
+    from repro.fl.rounds import run_montecarlo
+    with trace.tracing() as tr:
+        res = run_montecarlo(n_clients=32, n_seeds=2, rounds=3, seed=5,
+                             policies=("age_noma",))
+    (call,) = [s for s in tr.spans if s.name == "mc.call"]
+    assert call.parent is None and call.meta == {"seed": 5, "drops": 6}
+    kids = {s.name: s for s in tr.spans if s.parent == "mc.call"}
+    assert set(kids) == {"mc.setup", "engine.mc_loop", "mc.collect"}
+    assert all(s.meta["seed"] == 5 for s in kids.values())
+    assert kids["mc.collect"].meta["bytes"] == sum(
+        v.nbytes for v in res["age_noma"].values())
+
+
+def test_montecarlo_step_stages_are_named_scopes():
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core.engine import _montecarlo_step
+    eng = WirelessEngine(NOMAConfig(), FLConfig())
+    s, n = 64, 3000                  # segmented admission in a sub-chunk scan
+    n_cand0 = min(eng.prm.slots, n)
+    x = jnp.ones((s, n), jnp.float32)
+    text = _montecarlo_step.lower(
+        x, x, x, jax.random.PRNGKey(0), x, x, jnp.float32(1e6),
+        jnp.int32(0), None, prm=eng.prm, gamma=1.0, policy="age_noma",
+        t_budget=0.0, n_pairs=(n_cand0 + 1) // 2, n_cand0=n_cand0,
+        admission="segmented").as_text(debug_info=True)
+    paths = re.findall(r'loc\("([^"]*)"', text)
+    for scope in ("mc.priority", "mc.admit", "mc.finish", "mc.ages"):
+        assert any(scope in p.split("/") for p in paths), scope
 
 
 def test_cold_fires_once_per_key():
@@ -99,20 +246,6 @@ def test_aou_histogram_buckets():
     # (edge[i-1], edge[i]] convention: age 1.0 lands in bucket 0, 1.5 and
     # 2.0 in bucket 1, 9 in (8, 16], 100 overflows into the last bucket
     assert h.tolist() == [2, 2, 1, 0, 1, 0, 1]
-
-
-def test_metrics_registry():
-    m = MetricsRegistry()
-    m.counter("rounds").inc()
-    m.counter("rounds").inc(2)
-    m.gauge("t").set(1.5)
-    m.histogram("age", edges=(1., 2.)).observe(1.5)
-    d = m.as_dict()
-    assert d["rounds"]["value"] == 3
-    assert d["t"]["value"] == 1.5
-    assert sum(d["age"]["counts"]) == 1
-    with pytest.raises(ValueError):
-        m.gauge("rounds")  # type mismatch on re-registration
 
 
 def test_json_safe_round_trips_through_json():
