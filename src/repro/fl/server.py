@@ -46,7 +46,7 @@ from repro.fl.aggregate import aggregate_deltas, apply_aggregate, \
     blend_deltas
 from repro.fl.client import LocalTrainer
 from repro.fl.predictor import UpdatePredictor
-from repro.kernels.backend import resolve_backend, resolve_impl
+from repro.kernels.backend import resolve_impl
 from repro.models import zoo
 from repro.obs import RunLedger, json_safe, trace
 from repro.sim import NumpyScenario, get_scenario_config
@@ -121,11 +121,11 @@ class FLServer:
         self.noma = nomacfg
         self.task = task
         self.policy = policy
-        # FedAvg reduction impl: by default the one FLConfig.kernel_backend
-        # resolves to here (the compiled fedagg kernel on a TPU, the XLA
-        # twin on the CPU)
-        self.agg_impl = (resolve_backend(fl.kernel_backend).impl
-                         if agg_impl is None else resolve_impl(agg_impl))
+        # FedAvg reduction impl: the fused sum in the deltas' own layouts
+        # whatever kernel_backend says (no kernel reads the leaves without a
+        # relayout, DESIGN.md section 3); agg_impl="pallas"/"interpret"
+        # stacks them for the fedagg kernel
+        self.agg_impl = resolve_impl(agg_impl or "xla")
         self.eval_every = eval_every
         self.predictor_mode = fl.predictor if predictor is None else predictor
         # batched wireless engine (core/engine.py) behind FLConfig.engine;
@@ -314,8 +314,9 @@ class FLServer:
         ``r`` = the round index): ``server.round`` over
         ``server.scenario``, ``server.select``, ``server.train`` (one
         ``client.update`` per client; counts ``clients`` and ``steps``, the
-        SGD steps dispatched) and ``server.aggregate`` (counts ``clients``
-        and ``bytes``, the deltas' logical bytes; fenced on the new
+        SGD steps dispatched) and ``server.aggregate`` (counts ``clients``,
+        ``bytes``, the deltas' logical bytes, and ``stacked_bytes``, the
+        stack the aggregation built: 0 on the fused path; fenced on the new
         parameters), ``server.predict`` inside it under the predictor."""
         r = self.round_idx
         with trace.span("server.round", r=r):
@@ -354,15 +355,16 @@ class FLServer:
             if deltas:
                 nbytes = len(deltas) * sum(
                     x.nbytes for x in jax.tree.leaves(deltas[0]))
+                # a stacking aggregation notes stacked_bytes over the 0
                 with trace.span("server.aggregate", r=r,
-                                clients=len(deltas), bytes=nbytes) as sp:
+                                clients=len(deltas), bytes=nbytes,
+                                stacked_bytes=0) as sp:
                     if self.predictor is None:
                         agg = aggregate_deltas(deltas, np.asarray(weights),
                                                impl=self.agg_impl)
                     else:
-                        with trace.span("server.predict", r=r):
-                            agg = self._aggregate_with_predictions(
-                                sel, deltas, weights)
+                        agg = self._aggregate_with_predictions(
+                            sel, deltas, weights)
                     self.params = apply_aggregate(self.params, agg)
                     sp.fence(self.params)
 
@@ -372,25 +374,27 @@ class FLServer:
         return sched
 
     def _aggregate_with_predictions(self, sel, deltas, weights):
-        """Predictor path: train on arrivals, predict the unselected, blend
-        with age-discounted weights -> the aggregate delta."""
+        """Predictor path: train on arrivals, predict the unselected (span
+        ``server.predict``), blend with age-discounted weights -> the
+        aggregate delta."""
         pred = self.predictor
-        data_w = self.n_samples / self.n_samples.sum()
-        flat = [pred.flatten(d) for d in deltas]
-        stats = pred.observe(sel, flat, self.ages, data_w)
+        with trace.span("server.predict", r=self.round_idx):
+            data_w = self.n_samples / self.n_samples.sum()
+            flat = [pred.flatten(d) for d in deltas]
+            stats = pred.observe(sel, flat, self.ages, data_w)
 
-        w_real = np.asarray(weights, np.float64)
-        wn = w_real / w_real.sum()
-        mean_flat = sum(wi * f for wi, f in zip(wn, flat))
-        selected = np.zeros(self.fl.n_clients, bool)
-        selected[sel] = True
-        targets = pred.predictable(selected, self.ages)
-        pred_flats = pred.predict(targets, self.ages, data_w, mean_flat)
-        pred_trees = [pred.unflatten(f) for f in pred_flats]
-        w_pred = (self.n_samples[targets] * self.fl.pred_blend
-                  * aoi.age_discount(self.ages[targets],
-                                     self.fl.pred_discount))
-        self.pred_stats = {"n_predicted": len(targets), **stats}
+            w_real = np.asarray(weights, np.float64)
+            wn = w_real / w_real.sum()
+            mean_flat = sum(wi * f for wi, f in zip(wn, flat))
+            selected = np.zeros(self.fl.n_clients, bool)
+            selected[sel] = True
+            targets = pred.predictable(selected, self.ages)
+            pred_flats = pred.predict(targets, self.ages, data_w, mean_flat)
+            pred_trees = [pred.unflatten(f) for f in pred_flats]
+            w_pred = (self.n_samples[targets] * self.fl.pred_blend
+                      * aoi.age_discount(self.ages[targets],
+                                         self.fl.pred_discount))
+            self.pred_stats = {"n_predicted": len(targets), **stats}
         return blend_deltas(deltas, w_real, pred_trees, w_pred,
                             impl=self.agg_impl)
 

@@ -1,5 +1,6 @@
 """Pallas TPU kernel: age/size-weighted aggregation of stacked client
-updates — the FedAvg server hot spot.
+updates — the FedAvg sum on the explicit ``pallas`` path (the default
+aggregation is the fused sum of ``fl.aggregate``, which needs no stack).
 
     out[n] = sum_c w[c] * updates[c, n]
 

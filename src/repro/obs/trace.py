@@ -50,8 +50,8 @@ import time
 from typing import Any, Optional
 
 __all__ = [
-    "Span", "Tracer", "tracing", "span", "get_tracer", "set_tracer",
-    "profile", "summarize", "format_report",
+    "Span", "Tracer", "tracing", "span", "note", "get_tracer",
+    "set_tracer", "profile", "summarize", "format_report",
 ]
 
 
@@ -145,7 +145,7 @@ class _SpanCtx:
         self._handle = _Handle(meta)
 
     def __enter__(self):
-        self._tracer._stack.append(self._name)
+        self._tracer._stack.append(self)
         self._ann = _annotation()(self._name,
                                   **_event_args(self._handle.meta))
         self._ann.__enter__()
@@ -165,7 +165,7 @@ class _SpanCtx:
         tr = self._tracer
         tr._stack.pop()
         depth = len(tr._stack)
-        parent = tr._stack[-1] if tr._stack else None
+        parent = tr._stack[-1]._name if tr._stack else None
         # a late note(cold=...) overrides the entry-time flag — for spans
         # whose static signature is only known mid-region (e.g. mc_loop
         # sees its (S, N) shape after the first env_fn call)
@@ -184,13 +184,18 @@ class Tracer:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self.spans: list[Span] = []
-        self._stack: list[str] = []
+        self._stack: list[_SpanCtx] = []    # the open spans, innermost last
         self._seen: set = set()
 
     def span(self, name: str, *, cold: Optional[bool] = None, **meta):
         if not self.enabled:
             return _NULL_CTX
         return _SpanCtx(self, name, bool(cold), meta)
+
+    def note(self, **meta) -> None:
+        """``note(**meta)`` on the innermost open span, if one is open."""
+        if self._stack:
+            self._stack[-1]._handle.note(**meta)
 
     def cold(self, key: Any) -> bool:
         """True exactly once per ``key`` — mark a jitted entry point's
@@ -226,6 +231,13 @@ def set_tracer(tracer: Tracer) -> Tracer:
 def span(name: str, *, cold: Optional[bool] = None, **meta):
     """Open a span on the global tracer (no-op context when disabled)."""
     return _TRACER.span(name, cold=cold, **meta)
+
+
+def note(**meta) -> None:
+    """``Tracer.note`` on the global tracer: meta for the innermost open
+    span, from code that does not hold its handle (no-op when disabled)."""
+    if _TRACER.enabled:
+        _TRACER.note(**meta)
 
 
 def cold(key: Any) -> bool:

@@ -84,6 +84,88 @@ class TestAggregate:
         agg = aggregate_deltas([d], np.array([5.0]))
         np.testing.assert_allclose(np.asarray(agg["w"]), np.asarray(d["w"]))
 
+    # leaves of rank 0-4, minor dims off 128 lanes, second-minor off 8
+    LEAVES = {"r0": (), "r1": (7,), "r2": (5, 130), "r3": (3, 9, 129),
+              "r4": (2, 3, 17, 200)}
+
+    def _cohort(self, c, dtype, seed=0):
+        key = jax.random.PRNGKey(seed)
+        deltas = [{k: jax.random.normal(jax.random.fold_in(key, 8 * i + j),
+                                        s, jnp.float32).astype(dtype)
+                   for j, (k, s) in enumerate(self.LEAVES.items())}
+                  for i in range(c)]
+        w = np.random.default_rng(seed + c).uniform(0.1, 1.0, c)
+        # the weights as the program normalises them, in fp32
+        w32 = jnp.asarray(w, jnp.float32)
+        return deltas, w, w32 / jnp.maximum(jnp.sum(w32), 1e-9)
+
+    @staticmethod
+    def _within_summation_bound(out, expect, deltas, wn, name):
+        """|out - expect| <= c * eps * sum_c |w_c u_c| (fp64), elementwise:
+        the fp32 summation bound of a C-term weighted sum, doubled so that
+        two fp32 sums (each within c * eps / 2) may differ by it."""
+        u = np.stack([np.asarray(d[name], np.float64) for d in deltas])
+        w64 = np.asarray(wn, np.float64)
+        bound = (len(deltas) * np.finfo(np.float32).eps
+                 * np.tensordot(np.abs(w64), np.abs(u), 1))
+        gap = np.abs(np.asarray(out, np.float64)
+                     - np.asarray(expect, np.float64))
+        assert np.all(gap <= bound), name
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("c", [1, 10, 50])
+    def test_fused_matches_stacked_reference(self, c, dtype):
+        """The fused sum (default impl) against ``weighted_sum_ref`` on
+        stacked copies and the fp64 sum, leaf by leaf: fp32 leaves of the
+        deltas' own shapes, within the fp32 summation bound."""
+        from repro.kernels import ref
+        deltas, w, wn = self._cohort(c, dtype)
+        agg = aggregate_deltas(deltas, w)
+        for name, shape in self.LEAVES.items():
+            out = agg[name]
+            assert out.shape == shape and out.dtype == jnp.float32
+            stacked = jnp.stack([d[name] for d in deltas]).reshape(c, -1)
+            expect = ref.weighted_sum_ref(stacked, wn).reshape(shape)
+            self._within_summation_bound(out, expect, deltas, wn, name)
+            exact = np.tensordot(
+                np.asarray(wn, np.float64),
+                np.stack([np.asarray(d[name], np.float64) for d in deltas]),
+                1)
+            self._within_summation_bound(out, exact, deltas, wn, name)
+
+    @pytest.mark.parametrize("c", [1, 10, 50])
+    def test_fused_matches_kernel_path(self, c):
+        """The fused sum against the stacked ``fedagg`` kernel path
+        (interpret) within the fp32 summation bound."""
+        deltas, w, wn = self._cohort(c, jnp.float32, seed=1)
+        fused = aggregate_deltas(deltas, w, impl="xla")
+        kernel = aggregate_deltas(deltas, w, impl="interpret")
+        for name in self.LEAVES:
+            self._within_summation_bound(fused[name], kernel[name], deltas,
+                                         wn, name)
+
+    @pytest.mark.parametrize("impl", ["xla", "interpret"])
+    def test_blend_without_predictions_is_aggregate(self, impl):
+        from repro.fl import blend_deltas
+        deltas, w, _ = self._cohort(4, jnp.bfloat16, seed=2)
+        a = aggregate_deltas(deltas, w, impl=impl)
+        b = blend_deltas(deltas, w, [], np.zeros((0,)), impl=impl)
+        for name in self.LEAVES:
+            np.testing.assert_array_equal(np.asarray(a[name]),
+                                          np.asarray(b[name]))
+
+    def test_fused_path_stacks_nothing(self):
+        """The default path's program holds no stack, flattening, pad or
+        slice update of the deltas."""
+        import re
+
+        from repro.fl import aggregate
+        deltas, w, _ = self._cohort(10, jnp.float32)
+        text = str(jax.make_jaxpr(aggregate._fused_sum)(
+            deltas, jnp.asarray(w, jnp.float32)))
+        assert not re.search(
+            r"\b(concatenate|reshape|pad|dynamic_update_slice)\[", text)
+
     def test_apply_aggregate_moves_params(self):
         p = {"w": jnp.zeros((4,), jnp.float32)}
         d = {"w": jnp.ones((4,), jnp.float32)}

@@ -170,6 +170,50 @@ def test_run_round_spans_each_phase(predictor):
         assert pred.parent == "server.aggregate"
 
 
+@pytest.mark.parametrize("backend,agg_impl,impl", [
+    ("auto", None, "xla"), ("pallas_interpret", None, "xla"),
+    ("auto", "interpret", "interpret")])
+def test_aggregate_counts_stacked_bytes(backend, agg_impl, impl):
+    """``server.aggregate`` carries ``stacked_bytes``: 0 on the fused path,
+    which every ``kernel_backend`` aggregates with, C x the delta bytes on
+    the stacked kernel path that ``agg_impl`` asks for."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.data import TaskConfig
+    from repro.fl import FLServer
+    cfg = dataclasses.replace(get_config("smollm_135m").reduced(),
+                              d_model=32, d_ff=64, vocab_size=32, n_layers=2)
+    srv = FLServer(cfg, FLConfig(n_clients=8, local_batch=8, lr=0.2,
+                                 samples_per_client=(24, 48), seed=0,
+                                 kernel_backend=backend),
+                   NOMAConfig(n_subchannels=2),
+                   TaskConfig(vocab_size=32, n_topics=4, seq_len=17, seed=0),
+                   agg_impl=agg_impl)
+    assert srv.agg_impl == impl
+    with trace.tracing() as tr:
+        srv.run_round()
+    (agg,) = [s for s in tr.spans if s.name == "server.aggregate"]
+    leaf_bytes = sum(x.nbytes for x in jax.tree.leaves(srv.params))
+    assert agg.meta["clients"] > 0
+    assert agg.meta["stacked_bytes"] == (
+        0 if impl == "xla" else agg.meta["clients"] * leaf_bytes)
+
+
+def test_note_reaches_the_innermost_open_span():
+    """``trace.note`` adds to the innermost open span, and is a no-op with
+    no span open or with tracing off."""
+    trace.note(x=1)
+    with trace.tracing() as tr:
+        trace.note(x=2)
+        with trace.span("outer", a=0):
+            with trace.span("inner"):
+                trace.note(x=3)
+            trace.note(a=4)
+    by = {s.name: s.meta for s in tr.spans}
+    assert by == {"inner": {"x": 3}, "outer": {"a": 4}}
+
+
 def test_run_montecarlo_spans_each_phase():
     from repro.fl.rounds import run_montecarlo
     with trace.tracing() as tr:

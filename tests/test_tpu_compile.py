@@ -85,6 +85,31 @@ def test_fedagg_smollm_leaf(one_chip, c):
     _assert_kernel(compiled)
 
 
+# the distinct leaf shapes of smollm_135m (11 leaves: the (30, 576, 576)
+# and (30, 576, 192) projections and the (30, 576) norms come in pairs)
+SMOLLM_LEAVES = [(30, 576, 1536), (30, 1536, 576), (30, 576, 576),
+                 (30, 576, 192), (30, 576), (49_152, 576), (576,)]
+# scoped buffers the fusion keeps per operand, whatever the leaf's size
+OPERAND_TEMP = 64 * 1024
+
+
+@pytest.mark.parametrize("shape", SMOLLM_LEAVES)
+@pytest.mark.parametrize("c", [10, 50])
+def test_fused_aggregate_smollm_leaf(one_chip, c, shape):
+    """The default aggregation (one fused fp32 sum over the C deltas in
+    their own layouts) compiles with no relayout copy, no loop of copies
+    and no concatenation, and with no temporary that grows with the leaf:
+    under 64 KiB an operand, which is under one leaf's bytes for every
+    leaf over C x 64 KiB, so no (C, N) stack exists."""
+    from repro.fl.aggregate import _fused_sum
+    deltas = [{"leaf": _sds(shape, one_chip)} for _ in range(c)]
+    compiled = _fused_sum.lower(deltas, _sds((c,), one_chip)).compile()
+    text = compiled.as_text()
+    assert "copy(" not in text and "while" not in text
+    assert "concatenate" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < c * OPERAND_TEMP
+
+
 def test_engine_fast_path_hungarian(one_chip):
     """The engine's fused fast path with the planner kernel in it, at
     B=64 drops of N=1000 clients (segmented admission)."""
