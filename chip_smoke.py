@@ -208,9 +208,10 @@ def fl_round(ck: Checks):
     ck.true("aggregation runs the fedagg kernel", server.agg_impl == "pallas")
 
     real = server_mod.aggregate_deltas
-    ratios = []
+    ratios, folds = [], []
 
     def checked(deltas, weights, *, impl):
+        folds.append(len(deltas))
         out = real(deltas, weights, impl=impl)
         twin = real(deltas, weights, impl="xla")
         c = len(deltas)
@@ -236,8 +237,9 @@ def fl_round(ck: Checks):
     ck.true("loss is finite", bool(np.all(np.isfinite(hist.loss))))
     ck.true("t_round > 0", all(t > 0 for t in hist.round_time))
     ck.true("parameters changed", bool(np.any(before != after)))
-    ck.true("every round's aggregate was checked",
-            len(ratios) == 3 * len(jax.tree.leaves(server.params)))
+    ck.true("every fold of the running FedAvg was checked",
+            len(ratios) == len(folds) * len(jax.tree.leaves(server.params))
+            and len(folds) == sum(hist.n_selected))
     ck.true("fedagg aggregate == XLA twin within the fp32 bound",
             bool(ratios) and max(ratios) <= 1.0,
             f"max err/bound={max(ratios, default=float('nan')):.3e}")

@@ -34,13 +34,24 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0       # 0 -> d_model // n_heads
 
-    # --- MoE ---
-    n_experts: int = 0
+    # --- MoE (dropless; models/moe.py, DESIGN.md section 3) ---
+    n_experts: int = 0      # routed experts the router scores
     top_k: int = 0
-    capacity_factor: float = 1.25
-    moe_shard_hints: bool = False   # §Perf lever: constrain expert buffers
-                                    # (E->model, C->data) for reduce-scatter
-                                    # dispatch instead of all-reduce
+    router: str = "softmax"  # softmax: top-k of softmax, renormalised |
+                             # sigmoid: top-k of sigmoid + bias (noaux_tc)
+    routed_scale: float = 1.0   # sigmoid router's routed_scaling_factor
+    experts_held: int = 0       # routed experts held here (0 = all): an
+    first_held_expert: int = 0  # expert-parallel share [first, first+held)
+    n_shared_experts: int = 0   # shared experts: one SwiGLU of width
+                                # n_shared_experts * d_ff, every token
+    first_dense_layers: int = 0  # leading dense layers (first_k_dense_replace)
+    dense_d_ff: int = 0          # their SwiGLU width
+
+    # --- multi-head latent attention (MLA; 0 = standard attention) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM / RWKV / hybrid ---
     ssm_state: int = 0      # mamba-style per-channel state size
@@ -69,8 +80,22 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     def __post_init__(self) -> None:
-        if self.head_dim == 0 and self.n_heads > 0:
+        if self.kv_lora_rank:
+            # MLA: query/key heads carry the nope and rope parts
+            object.__setattr__(self, "head_dim", self.qk_nope_head_dim
+                               + self.qk_rope_head_dim)
+        elif self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r} "
+                             "(expected 'softmax' or 'sigmoid')")
+        if self.n_experts and not (
+                0 <= self.first_held_expert
+                and self.first_held_expert + self.n_held <= self.n_experts):
+            raise ValueError(
+                f"held experts [{self.first_held_expert}, "
+                f"{self.first_held_expert + self.n_held}) lie outside the "
+                f"{self.n_experts} routed experts")
 
     # -- derived ----------------------------------------------------------
     @property
@@ -86,6 +111,20 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def v_dim(self) -> int:
+        """Per-head value width."""
+        return self.v_head_dim or self.head_dim
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -94,30 +133,52 @@ class ModelConfig:
         """Natively supports 500k decode without a full KV cache."""
         return self.family in ("ssm", "hybrid")
 
+    def attn_param_count(self) -> int:
+        """Weights of one attention sub-block (its norms not counted)."""
+        d, h = self.d_model, self.n_heads
+        if self.is_mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return (d * h * self.head_dim + d * (r + rope) + r
+                    + r * h * (self.qk_nope_head_dim + self.v_dim)
+                    + h * self.v_dim * d)
+        q, kv = h * self.head_dim, self.n_kv_heads * self.head_dim
+        return d * q + 2 * d * kv + q * d
+
+    def _mlp_params(self, width: int) -> int:
+        return self.d_model * width * (3 if self.glu else 2)
+
+    def _moe_layer_params(self, held: int) -> int:
+        """Router (and the sigmoid router's bias), ``held`` routed experts
+        and the shared experts of one MoE layer."""
+        e = self.n_experts
+        return (self.d_model * e + (e if self.router == "sigmoid" else 0)
+                + held * self._mlp_params(self.d_ff)
+                + self._mlp_params(self.n_shared_experts * self.d_ff))
+
     def param_count(self) -> int:
-        """Analytic parameter count (embeddings + blocks + head)."""
+        """Analytic parameter count (embeddings + blocks + head, RMSNorm
+        scales included); a MoE layer counts the experts held here."""
         d, ff, v = self.d_model, self.d_ff, self.vocab_size
         emb = v * d
         head = 0 if self.tie_embeddings else v * d
         blocks = 0
         n_dec = self.n_layers
         hd = self.head_dim
-        for _ in range(n_dec):
-            blk = 0
+        for i in range(n_dec):
+            blk = 2 * d  # ln1, ln2
             if self.family == "ssm":  # rwkv6: time-mix + channel-mix
                 blk += 4 * d * d + d * d  # r,k,v,o + gate
                 blk += d * ff + ff * d    # channel mix (k, v)
             else:
-                q = self.n_heads * hd
-                kv = self.n_kv_heads * hd
-                blk += d * q + 2 * d * kv + q * d  # qkvo
+                blk += self.attn_param_count()
                 if self.family == "hybrid":
                     blk += 2 * d * d + d * self.ssm_state * 2  # ssm branch approx
-                if self.is_moe:
-                    mlp = d * ff * (3 if self.glu else 2)
-                    blk += self.n_experts * mlp + d * self.n_experts  # + router
+                if self.is_moe and i < self.first_dense_layers:
+                    blk += self._mlp_params(self.dense_d_ff)
+                elif self.is_moe:
+                    blk += self._moe_layer_params(self.n_held)
                 else:
-                    blk += d * ff * (3 if self.glu else 2)
+                    blk += self._mlp_params(ff)
             blocks += blk
         enc = 0
         for _ in range(self.n_enc_layers):
@@ -127,16 +188,18 @@ class ModelConfig:
             enc += d * ff * (3 if self.glu else 2)
             # decoder cross-attention counted per decoder layer
         cross = self.n_enc_layers and n_dec * (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d)
-        return emb + head + blocks + enc + (cross or 0)
+        return emb + head + blocks + d + enc + (cross or 0)
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: only top_k experts active)."""
+        """Params touched per token (MoE: top_k of the n_experts routed
+        experts, so top_k * held / n_experts of those held here)."""
         if not self.is_moe:
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        mlp = d * ff * (3 if self.glu else 2)
-        inactive = self.n_layers * (self.n_experts - self.top_k) * mlp
-        return self.param_count() - inactive
+        n_moe = self.n_layers - self.first_dense_layers
+        mlp = self._mlp_params(self.d_ff)
+        inactive = n_moe * mlp * (self.n_held - self.top_k * self.n_held
+                                  / self.n_experts)
+        return self.param_count() - int(round(inactive))
 
     # -- reduced variant for CPU smoke tests ------------------------------
     def reduced(self) -> "ModelConfig":
@@ -150,6 +213,12 @@ class ModelConfig:
             hd = 16
         else:
             n_heads = kv = hd = 0
+        mla = {}
+        if self.is_mla:
+            kv = n_heads   # MLA: one key/value per query head
+            mla = dict(kv_lora_rank=min(self.kv_lora_rank, 32),
+                       qk_nope_head_dim=16, qk_rope_head_dim=8,
+                       v_head_dim=16)
         return dataclasses.replace(
             self,
             n_layers=2,
@@ -161,12 +230,16 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            experts_held=0, first_held_expert=0,
+            first_dense_layers=min(self.first_dense_layers, 1),
+            dense_d_ff=min(self.dense_d_ff, 4 * d),
             n_enc_layers=2 if self.n_enc_layers else 0,
             n_prefix_tokens=min(self.n_prefix_tokens, 8) if self.n_prefix_tokens else 0,
             prefix_dim=d if self.prefix_dim else 0,
             rwkv_head_size=min(self.rwkv_head_size, 16) if self.rwkv_head_size else 0,
             long_context_window=256,
             dtype="float32",
+            **mla,
         )
 
 

@@ -10,10 +10,17 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import zoo
+from repro.obs import trace
 from repro.optim import SGD, apply_updates
 
 
 def make_sgd_batch_step(cfg: ModelConfig, lr: float, momentum: float = 0.0):
+    """(optimizer, step): ``step(params, opt_state, tokens) -> (params',
+    opt_state', metrics)``; ``metrics`` holds the ``loss`` and, for a MoE
+    model, the step's routing counters (summed over its MoE layers:
+    ``moe_routed`` pairs routed to the held experts, ``moe_rows`` rows
+    given to the expert products; ``moe_max_load``, the largest held
+    expert's load in any layer)."""
     opt = SGD(lr=lr, momentum=momentum)
 
     @jax.jit
@@ -21,12 +28,20 @@ def make_sgd_batch_step(cfg: ModelConfig, lr: float, momentum: float = 0.0):
         batch = {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
 
         def loss_fn(p):
-            logits, aux = zoo.forward(cfg, p, batch, remat=False)
-            return zoo.token_loss(cfg, logits, batch["labels"], aux=aux)
+            logits, aux, stats = zoo.forward(cfg, p, batch, remat=False,
+                                             stats=True)
+            return (zoo.token_loss(cfg, logits, batch["labels"], aux=aux),
+                    stats)
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params)
         upd, opt_state = opt.update(grads, opt_state, params)
-        return apply_updates(params, upd), opt_state, loss
+        metrics = {"loss": loss}
+        if cfg.is_moe:
+            metrics.update(moe_routed=jnp.sum(stats["routed"]),
+                           moe_max_load=jnp.max(stats["max_load"]),
+                           moe_rows=jnp.sum(stats["rows"]))
+        return apply_updates(params, upd), opt_state, metrics
 
     return opt, step
 
@@ -38,14 +53,25 @@ class LocalTrainer:
     def __init__(self, cfg: ModelConfig, lr: float, momentum: float = 0.0):
         self.cfg = cfg
         self.opt, self.step = make_sgd_batch_step(cfg, lr, momentum)
+        self.counters = {}
 
     def local_update(self, params, batches: Iterable[np.ndarray]):
+        """-> (fp32 delta, mean loss). The step's counters other than the
+        loss come to the host with it, one transfer a step; their sums
+        over the steps are ``self.counters`` and notes of the open span."""
         p = params
         opt_state = self.opt.init(params)
-        losses = []
+        losses, counters = [], {}
         for tokens in batches:
-            p, opt_state, loss = self.step(p, opt_state, jnp.asarray(tokens))
-            losses.append(float(loss))
+            p, opt_state, metrics = self.step(p, opt_state,
+                                              jnp.asarray(tokens))
+            metrics = jax.device_get(metrics)
+            losses.append(float(metrics.pop("loss")))
+            for k, v in metrics.items():
+                counters[k] = counters.get(k, 0) + int(v)
+        self.counters = counters
+        if counters:
+            trace.note(**counters)
         delta = jax.tree.map(
             lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
             p, params)

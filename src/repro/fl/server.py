@@ -9,11 +9,13 @@ Per round:
   2. run the selection policy -> Schedule (mask, pairs, powers, rates, T)
      via the shared ``select()`` path (every policy, with or without the
      update predictor);
-  3. run local SGD for selected clients; collect deltas;
-  4. when ``predictor != "none"``: train the server-side ANN on the
-     arrivals, predict deltas for unselected clients, and blend them in
-     with age-discounted weights (repro.fl.predictor);
-  5. FedAvg-aggregate (kernels.fedagg path) and apply;
+  3. run local SGD for selected clients, folding each delta into a
+     running FedAvg as it arrives (at most one delta alive at a time);
+  4. when ``predictor != "none"``: collect the deltas instead, train the
+     server-side ANN on the arrivals, predict deltas for unselected
+     clients, and blend them in with age-discounted weights
+     (repro.fl.predictor);
+  5. apply the aggregate;
   6. advance ages and the simulated wall clock by T_round.
 """
 from __future__ import annotations
@@ -312,12 +314,14 @@ class FLServer:
     def run_round(self) -> Schedule:
         """One round, spanned at each phase (``obs.trace``, every span with
         ``r`` = the round index): ``server.round`` over
-        ``server.scenario``, ``server.select``, ``server.train`` (one
-        ``client.update`` per client; counts ``clients`` and ``steps``, the
-        SGD steps dispatched) and ``server.aggregate`` (counts ``clients``,
-        ``bytes``, the deltas' logical bytes, and ``stacked_bytes``, the
-        stack the aggregation built: 0 on the fused path; fenced on the new
-        parameters), ``server.predict`` inside it under the predictor."""
+        ``server.scenario``, ``server.select``, ``server.train`` (counts
+        ``clients`` and ``steps``, the SGD steps dispatched; per client a
+        ``client.update``, with a MoE model's routing counters, then a
+        ``server.fold`` of its delta into the running FedAvg, with the
+        ``client`` and the delta's ``bytes``, and ``stacked_bytes`` where a
+        kernel path stacks) and ``server.aggregate`` (counts ``clients``;
+        ``apply_aggregate``, fenced on the new parameters;
+        ``server.predict`` inside it under the predictor)."""
         r = self.round_idx
         with trace.span("server.round", r=r):
             # advance the wireless environment; under dynamic scenarios the
@@ -334,7 +338,10 @@ class FLServer:
                 sched = self.select(env)
 
             sel = np.flatnonzero(sched.selected)
-            deltas, weights = [], []
+            # running FedAvg: each delta is folded into the aggregate as it
+            # arrives, so at most one delta is alive beside it; the
+            # predictor, which flattens every delta, keeps the list
+            agg, total, deltas, weights = None, 0.0, [], []
             with trace.span("server.train", r=r, clients=len(sel)) as sp:
                 steps = 0
                 for ci in sel:
@@ -347,22 +354,29 @@ class FLServer:
                         delta, _ = self.trainer.local_update(self.params,
                                                              batches)
                     steps += len(batches)
-                    deltas.append(delta)
-                    weights.append(self.n_samples[ci])
+                    w = self.n_samples[ci]
+                    if self.predictor is not None:
+                        deltas.append(delta)
+                        weights.append(w)
+                        continue
+                    # a stacking aggregation notes stacked_bytes over the 0
+                    with trace.span("server.fold", r=r, client=int(ci),
+                                    bytes=sum(x.nbytes for x in
+                                              jax.tree.leaves(delta)),
+                                    stacked_bytes=0):
+                        agg = aggregate_deltas(
+                            [delta] if agg is None else [agg, delta],
+                            np.array([w] if agg is None else [total, w]),
+                            impl=self.agg_impl)
+                    total += w
+                    del delta
                 sp.note(steps=steps)
             self.pred_stats = {"n_predicted": 0, "pred_loss": float("nan"),
                                "pred_error": float("nan")}
-            if deltas:
-                nbytes = len(deltas) * sum(
-                    x.nbytes for x in jax.tree.leaves(deltas[0]))
-                # a stacking aggregation notes stacked_bytes over the 0
+            if len(sel):
                 with trace.span("server.aggregate", r=r,
-                                clients=len(deltas), bytes=nbytes,
-                                stacked_bytes=0) as sp:
-                    if self.predictor is None:
-                        agg = aggregate_deltas(deltas, np.asarray(weights),
-                                               impl=self.agg_impl)
-                    else:
+                                clients=len(sel)) as sp:
+                    if self.predictor is not None:
                         agg = self._aggregate_with_predictions(
                             sel, deltas, weights)
                     self.params = apply_aggregate(self.params, agg)

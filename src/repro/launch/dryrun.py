@@ -112,14 +112,9 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                verbose: bool = True, variant: dict | None = None) -> dict:
     """``variant``: optional §Perf-lever overrides, e.g.
     {"accum_dtype": "bfloat16", "act_model_shard": True, "micro": 8,
-     "capacity_factor": 1.0, "note": "tag"}."""
+     "note": "tag"}."""
     variant = variant or {}
     cfg = get_config(arch)
-    if "capacity_factor" in variant:
-        cfg = dataclasses.replace(
-            cfg, capacity_factor=variant["capacity_factor"])
-    if variant.get("moe_shard_hints"):
-        cfg = dataclasses.replace(cfg, moe_shard_hints=True)
     if "long_context_window" in variant:
         cfg = dataclasses.replace(
             cfg, long_context_window=variant["long_context_window"])

@@ -24,21 +24,13 @@ PAIRS = {
             {"note": "act_model_shard", "act_model_shard": True},
             {"note": "bf16+actshard", "accum_dtype": "bfloat16",
              "act_model_shard": True},
-            {"note": "bf16+actshard+cap1.0", "accum_dtype": "bfloat16",
-             "act_model_shard": True, "capacity_factor": 1.0},
         ],
     },
     "llama4_prefill": {
         "arch": "llama4_maverick_400b_a17b", "shape": "prefill_32k",
         "variants": [
             {"note": "baseline"},
-            {"note": "cap1.0", "capacity_factor": 1.0},
-            {"note": "moe_hints", "moe_shard_hints": True},
-            {"note": "moe_hints+cap1.0", "moe_shard_hints": True,
-             "capacity_factor": 1.0},
             {"note": "ring_attn", "ring_attn": True},
-            {"note": "ring_attn+cap1.0", "ring_attn": True,
-             "capacity_factor": 1.0},
         ],
     },
     "smollm_train": {

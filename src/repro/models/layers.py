@@ -1,5 +1,5 @@
-"""Core transformer layers: norms, RoPE, GQA attention (flash-chunked,
-sliding-window, KV-cache decode), MLPs.
+"""Core transformer layers: norms, RoPE, GQA and multi-head latent
+attention (flash-chunked, sliding-window, KV-cache decode), MLPs.
 
 All modules are functional: ``init_*`` returns ``(params, specs)`` where
 ``specs`` is a pytree of *logical* axis-name tuples mirroring ``params``.
@@ -158,6 +158,55 @@ def out_proj(p, attn_out):
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (MLA, DeepSeek-V2/V3; q_lora_rank null)
+# ---------------------------------------------------------------------------
+
+# kv_a_layernorm keeps the RMSNorm module's default eps in the DeepSeek-V3
+# modelling code; the block norms take the config's rms_norm_eps
+MLA_KV_NORM_EPS = 1e-6
+
+
+def init_mla(key, cfg: ModelConfig, dtype) -> tuple[Params, Specs]:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    rope, nope, vd = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.v_dim
+    ks = jax.random.split(key, 4)
+    params = {
+        "wq": dense_init(ks[0], (d, h * cfg.head_dim), dtype),
+        "wkv_a": dense_init(ks[1], (d, r + rope), dtype),
+        "kv_norm": ones_init((r,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (r, h * (nope + vd)), dtype),
+        "wo": dense_init(ks[3], (h * vd, d), dtype,
+                         scale=1.0 / math.sqrt(h * vd)),
+    }
+    specs = {"wq": ("embed", "qdim"), "wkv_a": ("embed", None),
+             "kv_norm": (None,), "wkv_b": (None, "qdim"),
+             "wo": ("qdim", "embed")}
+    return params, specs
+
+
+def mla_qkv(p, x, positions, cfg: ModelConfig):
+    """x (B,S,D) -> q, k (B,S,H,nope+rope), v (B,S,H,v_dim): queries from
+    ``wq``; keys' nope part and values up-projected from the normed latent
+    ``c`` of ``wkv_a``, whose rope part ``k_pe`` is one key shared by every
+    head; RoPE (rotate-half) on ``q_pe`` and ``k_pe``."""
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_dim
+    q = (x @ p["wq"]).reshape(b, s, h, cfg.head_dim)
+    kva = x @ p["wkv_a"]
+    c = rms_norm(kva[..., :r], p["kv_norm"], MLA_KV_NORM_EPS)
+    kv = (c @ p["wkv_b"]).reshape(b, s, h, nope + vd)
+    cos, sin = rope_angles(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    q_pe = apply_rope(q[..., nope:], cos, sin, 1.0)
+    k_pe = apply_rope(kva[..., None, r:], cos, sin, 1.0)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, k_pe.shape[-1]))],
+        axis=-1)
+    return q, k, kv[..., nope:]
+
+
+# ---------------------------------------------------------------------------
 # chunked flash attention (pure jnp; the Pallas twin lives in repro.kernels)
 # ---------------------------------------------------------------------------
 
@@ -208,7 +257,7 @@ def _direct_attention(q, k, v, cfg: ModelConfig, *, causal, window,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskh->bqkgh", p, v.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, sq, h, hd).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool = True,
@@ -222,9 +271,10 @@ def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool = True,
     attended by everything before them), and banded sliding windows
     (``window`` > 0: position i attends to j in (i-window, i]).
 
-    Returns (B, Sq, H, hd) in q.dtype.
+    Returns (B, Sq, H, hd_v) in q.dtype (``hd_v`` = v's head width).
     """
     b, sq, h, hd = q.shape
+    hdv = v.shape[-1]
     skv = k.shape[1]
     kh = cfg.n_kv_heads
     g = h // kh
@@ -240,7 +290,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool = True,
 
     qb = q.reshape(b, nq, q_chunk, kh, g, hd).astype(jnp.float32) * scale
     kb = k.reshape(b, nkv, kv_chunk, kh, hd).astype(jnp.float32)
-    vb = v.reshape(b, nkv, kv_chunk, kh, hd).astype(jnp.float32)
+    vb = v.reshape(b, nkv, kv_chunk, kh, hdv).astype(jnp.float32)
 
     q_pos = jnp.arange(sq).reshape(nq, q_chunk)
     k_pos = jnp.arange(skv).reshape(nkv, kv_chunk)
@@ -276,7 +326,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool = True,
             acc_new = acc * corr[..., None] + pv
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((b, kh, g, q_chunk, hd), jnp.float32)
+        acc0 = jnp.zeros((b, kh, g, q_chunk, hdv), jnp.float32)
         m0 = jnp.full((b, kh, g, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, kh, g, q_chunk), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(kv_step, (acc0, m0, l0),
@@ -290,8 +340,8 @@ def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool = True,
         q_block)
     outs = jax.lax.map(lambda i: q_block_ckpt(i, qb[:, i]), jnp.arange(nq))
     # (nq, B, Cq, KH, G, hd) -> (B, Sq, H, hd)
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq, kh, g, hd)
-    return out.reshape(b, sq, h, hd).astype(q.dtype)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq, kh, g, hdv)
+    return out.reshape(b, sq, h, hdv).astype(q.dtype)
 
 
 def ring_flash_attention(q, k, v, cfg: ModelConfig, mesh, *,
@@ -387,7 +437,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, cfg: ModelConfig):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskh->bqkgh", p, v_cache.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, 1, h, hd).astype(q.dtype)
+    return out.reshape(b, 1, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -402,7 +452,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_layers: int,
     kh, hd = cfg.n_kv_heads, cfg.head_dim
     return {
         "k": jnp.zeros((n_layers, batch, max_len, kh, hd), dtype),
-        "v": jnp.zeros((n_layers, batch, max_len, kh, hd), dtype),
+        "v": jnp.zeros((n_layers, batch, max_len, kh, cfg.v_dim), dtype),
         "pos": jnp.full((n_layers, batch, max_len), -1, jnp.int32),
     }
 
@@ -437,8 +487,10 @@ def cache_write(cache_k, cache_v, cache_pos, k_new, v_new, pos, ring: bool):
 # ---------------------------------------------------------------------------
 
 
-def init_mlp(key, cfg: ModelConfig, dtype) -> tuple[Params, Specs]:
-    d, f = cfg.d_model, cfg.d_ff
+def init_mlp(key, cfg: ModelConfig, dtype, width: int = 0
+             ) -> tuple[Params, Specs]:
+    """The MLP of width ``width`` (default ``cfg.d_ff``)."""
+    d, f = cfg.d_model, width or cfg.d_ff
     ks = jax.random.split(key, 3)
     if cfg.glu:
         params = {

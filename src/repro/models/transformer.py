@@ -9,6 +9,7 @@ which is what makes 64-layer x 512-device dry-run compiles tractable
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -28,7 +29,9 @@ Params = Any
 # ---------------------------------------------------------------------------
 
 
-def _init_block(key, cfg: ModelConfig, dtype):
+def _init_block(key, cfg: ModelConfig, dtype, dense: bool = False):
+    """One layer; ``dense``: a leading dense layer of a MoE model (its MLP
+    of width ``dense_d_ff``)."""
     ks = jax.random.split(key, 4)
     if cfg.family == "ssm":  # rwkv6
         tm, tm_s = RWKV.init_rwkv_time_mix(ks[0], cfg, dtype)
@@ -38,7 +41,8 @@ def _init_block(key, cfg: ModelConfig, dtype):
         specs = {"ln1": ("embed",), "tm": tm_s, "ln2": ("embed",), "cm": cm_s}
         return params, specs
 
-    attn, attn_s = L.init_attention(ks[0], cfg, dtype)
+    attn, attn_s = (L.init_mla if cfg.is_mla else L.init_attention)(
+        ks[0], cfg, dtype)
     params = {"ln1": L.ones_init((cfg.d_model,), jnp.float32), "attn": attn,
               "ln2": L.ones_init((cfg.d_model,), jnp.float32)}
     specs = {"ln1": ("embed",), "attn": attn_s, "ln2": ("embed",)}
@@ -52,27 +56,41 @@ def _init_block(key, cfg: ModelConfig, dtype):
         specs["ln_attn_o"] = ("embed",)
         specs["ln_ssm_o"] = ("embed",)
 
-    if cfg.is_moe:
+    if cfg.is_moe and not dense:
         moe_p, moe_s = MOE.init_moe(ks[1], cfg, dtype)
         params["moe"] = moe_p
         specs["moe"] = moe_s
     else:
-        mlp_p, mlp_s = L.init_mlp(ks[1], cfg, dtype)
+        mlp_p, mlp_s = L.init_mlp(ks[1], cfg, dtype,
+                                  width=cfg.dense_d_ff if dense else 0)
         params["mlp"] = mlp_p
         specs["mlp"] = mlp_s
     return params, specs
 
 
+def _n_dense(cfg: ModelConfig) -> int:
+    """Leading dense layers ahead of a MoE stack."""
+    return cfg.first_dense_layers if cfg.is_moe else 0
+
+
+def _init_stack(keys, cfg: ModelConfig, dtype, dense: bool = False):
+    params = jax.vmap(lambda k: _init_block(k, cfg, dtype, dense)[0])(keys)
+    _, specs = _init_block(keys[0], cfg, dtype, dense)
+    specs = jax.tree.map(lambda s: ("layers",) + tuple(s), specs,
+                         is_leaf=lambda x: isinstance(x, tuple))
+    return params, specs
+
+
 def init_decoder(key, cfg: ModelConfig):
-    """Returns (params, specs) with blocks stacked on a leading layer axis."""
+    """Returns (params, specs) with blocks stacked on a leading layer axis;
+    a MoE model's leading dense layers are a stack of their own,
+    ``dense_blocks``."""
     dtype = jnp.dtype(cfg.dtype)
     k_emb, k_blocks, k_head, k_proj = jax.random.split(key, 4)
 
     layer_keys = jax.random.split(k_blocks, cfg.n_layers)
-    blocks = jax.vmap(lambda k: _init_block(k, cfg, dtype)[0])(layer_keys)
-    _, block_specs = _init_block(k_blocks, cfg, dtype)
-    block_specs = jax.tree.map(lambda s: ("layers",) + tuple(s), block_specs,
-                               is_leaf=lambda x: isinstance(x, tuple))
+    n_dense = _n_dense(cfg)
+    blocks, block_specs = _init_stack(layer_keys[n_dense:], cfg, dtype)
 
     params = {
         "embed": L.dense_init(k_emb, (cfg.padded_vocab, cfg.d_model), dtype,
@@ -85,6 +103,9 @@ def init_decoder(key, cfg: ModelConfig):
         "blocks": block_specs,
         "norm_f": ("embed",),
     }
+    if n_dense:
+        params["dense_blocks"], specs["dense_blocks"] = _init_stack(
+            layer_keys[:n_dense], cfg, dtype, dense=True)
     if not cfg.tie_embeddings:
         params["lm_head"] = L.dense_init(
             k_head, (cfg.d_model, cfg.padded_vocab), dtype)
@@ -101,12 +122,7 @@ def init_decoder(key, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _attn_seq(cfg, p, x, positions, *, window, prefix_len, collect_kv,
-              ring=None):
-    """Full-sequence attention sub-block. Returns (out, kv or None).
-
-    ``ring``: optional (mesh, batch_axis, seq_axis) enabling context-
-    parallel ring attention (prefill-only beyond-paper path)."""
+def _qkv_roped(cfg: ModelConfig, p, x, positions):
     q, k, v = L.qkv_proj(p, x, cfg)
     if cfg.rope_frac > 0:
         rot = int(cfg.head_dim * cfg.rope_frac)
@@ -114,6 +130,19 @@ def _attn_seq(cfg, p, x, positions, *, window, prefix_len, collect_kv,
         cos, sin = L.rope_angles(positions, rot, cfg.rope_theta)
         q = L.apply_rope(q, cos, sin, cfg.rope_frac)
         k = L.apply_rope(k, cos, sin, cfg.rope_frac)
+    return q, k, v
+
+
+def _attn_seq(cfg, p, x, positions, *, window, prefix_len, collect_kv,
+              ring=None):
+    """Full-sequence attention sub-block. Returns (out, kv or None).
+
+    ``ring``: optional (mesh, batch_axis, seq_axis) enabling context-
+    parallel ring attention (prefill-only beyond-paper path)."""
+    if cfg.is_mla:
+        q, k, v = L.mla_qkv(p, x, positions, cfg)
+    else:
+        q, k, v = _qkv_roped(cfg, p, x, positions)
     if ring is not None and window == 0 and prefix_len == 0:
         mesh, bax, sax = ring
         out = L.ring_flash_attention(q, k, v, cfg, mesh, batch_axis=bax,
@@ -129,12 +158,14 @@ def block_seq(cfg: ModelConfig, p, x, positions, *, window=0, prefix_len=0,
               collect_kv=False, states=None, ring=None):
     """One layer over a full sequence.
 
-    Returns (x_out, aux_loss, kv, new_states). ``states`` is the recurrent
-    state pytree for ssm/hybrid families (None for pure attention).
+    Returns (x_out, aux_loss, kv, new_states, stats). ``states`` is the
+    recurrent state pytree for ssm/hybrid families (None for pure
+    attention); ``stats`` the MoE layer's routing stats ({} elsewhere).
     """
     aux = jnp.zeros((), jnp.float32)
     kv = None
     new_states = None
+    stats = {}
 
     if cfg.family == "ssm":
         tm_in = L.rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -154,12 +185,14 @@ def block_seq(cfg: ModelConfig, p, x, positions, *, window=0, prefix_len=0,
         x = x + cm_out
         new_states = {"tm_shift": tm_shift_n, "cm_shift": cm_shift_n,
                       "wkv": wkv_n}
-        return x, aux, None, new_states
+        return x, aux, None, new_states, stats
 
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    attn_out, kv = _attn_seq(cfg, p["attn"], h, positions, window=window,
-                             prefix_len=prefix_len, collect_kv=collect_kv,
-                             ring=ring)
+    with (jax.named_scope("mla") if cfg.is_mla
+          else contextlib.nullcontext()):
+        attn_out, kv = _attn_seq(cfg, p["attn"], h, positions,
+                                 window=window, prefix_len=prefix_len,
+                                 collect_kv=collect_kv, ring=ring)
 
     if cfg.family == "hybrid":
         st = states or {}
@@ -172,12 +205,13 @@ def block_seq(cfg: ModelConfig, p, x, positions, *, window=0, prefix_len=0,
         x = x + attn_out
 
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        mo, aux = MOE.apply_moe(p["moe"], h2, cfg)
+    if "moe" in p:
+        mo, aux, stats = MOE.apply_moe(p["moe"], h2, cfg)
         x = x + mo
     else:
-        x = x + L.apply_mlp(p["mlp"], h2, cfg)
-    return x, aux, kv, new_states
+        with jax.named_scope("dense_mlp"):
+            x = x + L.apply_mlp(p["mlp"], h2, cfg)
+    return x, aux, kv, new_states, stats
 
 
 # ---------------------------------------------------------------------------
@@ -200,14 +234,11 @@ def block_decode(cfg: ModelConfig, p, x, cache, pos, *, ring: bool):
         return x, {"tm_shift": tm_shift, "cm_shift": cm_shift, "wkv": wkv}
 
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = L.qkv_proj(p["attn"], h, cfg)
-    if cfg.rope_frac > 0:
-        rot = int(cfg.head_dim * cfg.rope_frac)
-        rot -= rot % 2
-        posv = jnp.full((x.shape[0], 1), pos, jnp.int32)
-        cos, sin = L.rope_angles(posv, rot, cfg.rope_theta)
-        q = L.apply_rope(q, cos, sin, cfg.rope_frac)
-        k = L.apply_rope(k, cos, sin, cfg.rope_frac)
+    posv = jnp.full((x.shape[0], 1), pos, jnp.int32)
+    if cfg.is_mla:
+        q, k, v = L.mla_qkv(p["attn"], h, posv, cfg)
+    else:
+        q, k, v = _qkv_roped(cfg, p["attn"], h, posv)
     ck, cv, cp = L.cache_write(cache["k"], cache["v"], cache["pos"], k, v,
                                pos, ring)
     window = cfg.long_context_window if ring else 0
@@ -228,9 +259,8 @@ def block_decode(cfg: ModelConfig, p, x, cache, pos, *, ring: bool):
         x = x + attn_out
 
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        mo, _ = MOE.apply_moe(p["moe"], h2, cfg)
-        x = x + mo
+    if "moe" in p:
+        x = x + MOE.apply_moe(p["moe"], h2, cfg)[0]
     else:
         x = x + L.apply_mlp(p["mlp"], h2, cfg)
     return x, new_cache
@@ -295,56 +325,77 @@ def layer_pspecs(block_pspecs):
 def decoder_forward(cfg: ModelConfig, params, tokens, prefix_embeds=None, *,
                     window: int = 0, remat: bool = True,
                     collect_cache: bool = False, last_only: bool = False,
-                    block_pspecs=None, act_spec=None, ring=None):
-    """Full-sequence forward. Returns (logits, aux_loss[, cache]).
+                    block_pspecs=None, dense_pspecs=None, act_spec=None,
+                    ring=None, stats: bool = False):
+    """Full-sequence forward. Returns (logits, aux_loss[, cache][, stats]).
 
-    ``collect_cache=True`` additionally returns the stacked per-layer KV
-    cache / recurrent states (prefill mode).
+    A MoE model's leading dense layers (``dense_blocks``) run first, each
+    stack as one layer scan. ``collect_cache=True`` additionally returns
+    the stacked per-layer KV cache / recurrent states (prefill mode).
+    ``stats=True`` additionally returns the MoE layers' routing stats
+    stacked per layer (``moe.apply_moe``; {} for a model without MoE).
 
-    ``block_pspecs``: resolved PartitionSpec tree for the STACKED block
-    params. When given, each scan iteration re-constrains its layer slice —
-    without this, the scan-internal gradient accumulator for the stacked
-    weights materializes REPLICATED (catastrophic for the MoE archs)."""
+    ``block_pspecs`` / ``dense_pspecs``: resolved PartitionSpec trees for
+    the STACKED block params. When given, each scan iteration
+    re-constrains its layer slice — without this, the scan-internal
+    gradient accumulator for the stacked weights materializes REPLICATED
+    (catastrophic for the MoE archs)."""
     x, prefix_len = embed_inputs(cfg, params, tokens, prefix_embeds)
     b, s, _ = x.shape
     positions = jnp.tile(jnp.arange(s)[None], (b, 1))
     if cfg.family == "hybrid" and window == 0:
         window = cfg.long_context_window
-    lspecs = layer_pspecs(block_pspecs) if block_pspecs is not None else None
     if act_spec is not None:
         x = jax.lax.with_sharding_constraint(x, act_spec)
 
-    def body(x, layer_p):
-        if lspecs is not None:
-            layer_p = jax.lax.with_sharding_constraint(layer_p, lspecs)
-        if act_spec is not None:
-            x = jax.lax.with_sharding_constraint(x, act_spec)
-        st = _init_seq_states(cfg, b, x.dtype)
-        xo, aux, kv, st_n = block_seq(cfg, layer_p, x, positions,
-                                      window=window, prefix_len=prefix_len,
-                                      collect_kv=collect_cache, states=st,
-                                      ring=ring)
-        ys = {}
-        if collect_cache:
-            if kv is not None:
-                ys["k"], ys["v"] = kv
-                ys["pos"] = positions.astype(jnp.int32)
-            if st_n is not None:
-                ys.update(st_n)
-        return xo, (aux, ys)
+    def run(x, blocks, pspecs):
+        lspecs = layer_pspecs(pspecs) if pspecs is not None else None
 
-    if remat:
-        body = jax.checkpoint(body)
+        def body(x, layer_p):
+            if lspecs is not None:
+                layer_p = jax.lax.with_sharding_constraint(layer_p, lspecs)
+            if act_spec is not None:
+                x = jax.lax.with_sharding_constraint(x, act_spec)
+            st = _init_seq_states(cfg, b, x.dtype)
+            xo, aux, kv, st_n, layer_stats = block_seq(
+                cfg, layer_p, x, positions, window=window,
+                prefix_len=prefix_len, collect_kv=collect_cache, states=st,
+                ring=ring)
+            ys = {}
+            if collect_cache:
+                if kv is not None:
+                    ys["k"], ys["v"] = kv
+                    ys["pos"] = positions.astype(jnp.int32)
+                if st_n is not None:
+                    ys.update(st_n)
+            return xo, (aux, ys, layer_stats)
 
-    x, (auxs, caches) = jax.lax.scan(
-        lambda carry, lp: body(carry, lp), x, params["blocks"])
+        if remat:
+            body = jax.checkpoint(body)
+        return jax.lax.scan(body, x, blocks)
+
+    caches = []
+    if "dense_blocks" in params:
+        x, (_, cache_d, _) = run(x, params["dense_blocks"], dense_pspecs)
+        caches.append(cache_d)
+    x, (auxs, cache_m, layer_stats) = run(x, params["blocks"], block_pspecs)
+    caches.append(cache_m)
     if last_only:
         x = x[:, -1:]
     logits = unembed(cfg, params, x)
-    aux = jnp.sum(auxs)
+    out = (logits, jnp.sum(auxs))
     if collect_cache:
-        return logits, aux, caches
-    return logits, aux
+        out += (_concat_layers(caches),)
+    if stats:
+        out += (layer_stats,)
+    return out
+
+
+def _concat_layers(trees):
+    """Per-layer stacks (one per layer scan) as one stack."""
+    if len(trees) == 1:
+        return trees[0]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs), *trees)
 
 
 def decoder_decode(cfg: ModelConfig, params, cache, token, pos, *,
@@ -362,9 +413,17 @@ def decoder_decode(cfg: ModelConfig, params, cache, token, pos, *,
                                    ring=ring)
         return xo, cache_n
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    n_dense = _n_dense(cfg)
+    new_caches = []
+    if n_dense:
+        x, c = jax.lax.scan(body, x, (params["dense_blocks"], jax.tree.map(
+            lambda c: c[:n_dense], cache)))
+        new_caches.append(c)
+        cache = jax.tree.map(lambda c: c[n_dense:], cache)
+    x, c = jax.lax.scan(body, x, (params["blocks"], cache))
+    new_caches.append(c)
     logits = unembed(cfg, params, x[:, 0, :])
-    return logits, new_cache
+    return logits, _concat_layers(new_caches)
 
 
 def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):

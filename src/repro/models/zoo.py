@@ -187,22 +187,27 @@ def init_model(key, cfg: ModelConfig):
 
 
 def forward(cfg: ModelConfig, params, batch, *, remat=True, window=0,
-            param_pspecs=None, act_spec=None):
-    """Returns (logits, aux). ``batch`` dict may carry 'prefix' embeddings
+            param_pspecs=None, act_spec=None, stats=False):
+    """Returns (logits, aux), and with ``stats`` the per-layer MoE routing
+    stats of ``transformer.decoder_forward`` ({} without MoE). ``batch``
+    dict may carry 'prefix' embeddings
     (vlm) or 'frames' (encdec). ``param_pspecs``: resolved PartitionSpec
     tree matching params (block specs are re-constrained inside the layer
     scan; see transformer.decoder_forward). ``act_spec``: PartitionSpec for
     the (B,S,D) residual stream (pins batch onto the data axes — without it
     GSPMD may replicate activations across data)."""
     if cfg.family == "encdec":
-        return ED.encdec_forward(cfg, params, batch["frames"],
-                                 batch["tokens"], remat=remat, window=window,
-                                 block_pspecs=param_pspecs,
-                                 act_spec=act_spec)
-    bp = param_pspecs["blocks"] if param_pspecs is not None else None
+        out = ED.encdec_forward(cfg, params, batch["frames"],
+                                batch["tokens"], remat=remat, window=window,
+                                block_pspecs=param_pspecs,
+                                act_spec=act_spec)
+        return out + ({},) if stats else out
+    pspecs = param_pspecs or {}
     return T.decoder_forward(cfg, params, batch["tokens"],
                              batch.get("prefix"), remat=remat, window=window,
-                             block_pspecs=bp, act_spec=act_spec)
+                             block_pspecs=pspecs.get("blocks"),
+                             dense_pspecs=pspecs.get("dense_blocks"),
+                             act_spec=act_spec, stats=stats)
 
 
 # ---------------------------------------------------------------------------

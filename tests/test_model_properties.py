@@ -93,43 +93,57 @@ class TestMoE:
     @given(st.integers(0, 50))
     @settings(max_examples=15, deadline=None)
     def test_gates_normalized_and_capacity_respected(self, seed):
-        cfg = dataclasses.replace(get_config("grok_1_314b").reduced(),
-                                  capacity_factor=1.0)
-        params, _ = MOE.init_moe(jax.random.PRNGKey(seed), cfg,
-                                 jnp.float32), None
-        p = params[0]
+        """Softmax routing: the chosen gates sum to one, the load-balance
+        loss is positive, and no expert has a capacity: every (token,
+        choice) pair reaches its expert."""
+        cfg = get_config("grok_1_314b").reduced()
+        p, _ = MOE.init_moe(jax.random.PRNGKey(seed), cfg, jnp.float32)
         x = jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(seed),
                                                  7), (2, 16, cfg.d_model))
-        out, aux = MOE.apply_moe(p, x, cfg)
+        out, aux, stats = MOE.apply_moe(p, x, cfg)
         assert out.shape == x.shape
         assert bool(jnp.all(jnp.isfinite(out)))
         assert float(aux) > 0      # load-balance loss positive
+        w, _, _ = MOE.route(p, x.reshape(-1, cfg.d_model), cfg)
+        np.testing.assert_allclose(np.asarray(jnp.sum(w, -1)), 1.0,
+                                   rtol=1e-6)
+        assert int(stats["routed"]) == 32 * cfg.top_k == int(stats["rows"])
 
     def test_identical_tokens_identical_outputs(self):
-        """Permutation-ish invariance: two identical tokens that both fit
-        capacity get identical expert outputs."""
-        cfg = dataclasses.replace(get_config("grok_1_314b").reduced(),
-                                  capacity_factor=8.0)
+        """Permutation-ish invariance: two identical tokens get identical
+        expert outputs."""
+        cfg = get_config("grok_1_314b").reduced()
         p, _ = MOE.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         tok = jax.random.normal(jax.random.PRNGKey(1), (1, 1, cfg.d_model))
         x = jnp.tile(tok, (1, 4, 1))
-        out, _ = MOE.apply_moe(p, x, cfg)
+        out, _, _ = MOE.apply_moe(p, x, cfg)
         np.testing.assert_allclose(np.asarray(out[0, 0]),
                                    np.asarray(out[0, 3]), rtol=1e-5,
                                    atol=1e-5)
 
-    def test_dropped_tokens_pass_through_residual(self):
-        """capacity ~0 -> MoE output ~0 (residual carries the token)."""
-        cfg = dataclasses.replace(get_config("grok_1_314b").reduced(),
-                                  capacity_factor=1e-9)
+    @pytest.mark.parametrize("arch", ["grok_1_314b", "moonshot_v1_16b_a3b"])
+    def test_skewed_router_drops_no_token(self, arch):
+        """A router that sends every token to the same top-k experts (the
+        load a capacity would have cut at E/k of it): each token's output
+        is still its own experts' weighted sum, as computed one token at a
+        time."""
+        cfg = get_config(arch).reduced()
         p, _ = MOE.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
-        out, _ = MOE.apply_moe(p, x, cfg)
-        # cap clamps to top_k=2 -> only E*2=8 slots for 64 tokens; the
-        # overflow tokens must contribute exactly zero (residual carries
-        # them through untouched)
-        dropped_frac = float(jnp.mean(jnp.all(out == 0.0, axis=-1)))
-        assert dropped_frac > 0.3
+        # every token carries feature 0 = 1, which the router reads as a
+        # large score for experts 0..k-1
+        x = x.at[..., 0].set(1.0)
+        p = dict(p, router=p["router"].at[0, :cfg.top_k].add(100.0))
+        out, _, stats = MOE.apply_moe(p, x, cfg)
+        assert int(stats["max_load"]) == 64 == int(
+            stats["routed"]) // cfg.top_k
+        one = jnp.stack([MOE.apply_moe(p, x[b:b + 1, i:i + 1], cfg)[0][0, 0]
+                         for b in range(2) for i in range(32)])
+        # the same fp32 sums in another order (a grouped product over 64
+        # rows against one row): 1e-4 of the output's scale
+        np.testing.assert_allclose(np.asarray(out.reshape(64, -1)),
+                                   np.asarray(one), rtol=0,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(one))))
 
 
 class TestVocabPadding:

@@ -162,10 +162,16 @@ def test_run_round_spans_each_phase(predictor):
     assert train.meta["steps"] == len(calls) == sum(u.meta["steps"]
                                                     for u in updates) > 0
     leaf_bytes = sum(x.nbytes for x in jax.tree.leaves(srv.params))
-    assert agg.meta["bytes"] == len(updates) * leaf_bytes
     if predictor == "none":
         assert "server.predict" not in by
+        # the running FedAvg: one fold per client, inside server.train
+        folds = by["server.fold"]
+        assert [f.meta["client"] for f in folds] == [u.meta["client"]
+                                                     for u in updates]
+        assert all(f.parent == "server.train"
+                   and f.meta["bytes"] == leaf_bytes for f in folds)
     else:
+        assert "server.fold" not in by
         (pred,) = by["server.predict"]
         assert pred.parent == "server.aggregate"
 
@@ -174,9 +180,11 @@ def test_run_round_spans_each_phase(predictor):
     ("auto", None, "xla"), ("pallas_interpret", None, "xla"),
     ("auto", "interpret", "interpret")])
 def test_aggregate_counts_stacked_bytes(backend, agg_impl, impl):
-    """``server.aggregate`` carries ``stacked_bytes``: 0 on the fused path,
-    which every ``kernel_backend`` aggregates with, C x the delta bytes on
-    the stacked kernel path that ``agg_impl`` asks for."""
+    """Each ``server.fold`` carries ``stacked_bytes``: 0 on the fused path,
+    which every ``kernel_backend`` aggregates with; on the stacked kernel
+    path that ``agg_impl`` asks for, the bytes of the deltas it stacked:
+    one delta in the first fold, the aggregate and a delta in each other,
+    (2C - 1) x the delta bytes a round."""
     import dataclasses
 
     from repro.configs import get_config
@@ -194,10 +202,11 @@ def test_aggregate_counts_stacked_bytes(backend, agg_impl, impl):
     with trace.tracing() as tr:
         srv.run_round()
     (agg,) = [s for s in tr.spans if s.name == "server.aggregate"]
+    folds = [s for s in tr.spans if s.name == "server.fold"]
     leaf_bytes = sum(x.nbytes for x in jax.tree.leaves(srv.params))
-    assert agg.meta["clients"] > 0
-    assert agg.meta["stacked_bytes"] == (
-        0 if impl == "xla" else agg.meta["clients"] * leaf_bytes)
+    assert agg.meta["clients"] == len(folds) > 0
+    assert sum(f.meta["stacked_bytes"] for f in folds) == (
+        0 if impl == "xla" else (2 * len(folds) - 1) * leaf_bytes)
 
 
 def test_note_reaches_the_innermost_open_span():
