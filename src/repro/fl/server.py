@@ -316,10 +316,12 @@ class FLServer:
         ``r`` = the round index): ``server.round`` over
         ``server.scenario``, ``server.select``, ``server.train`` (counts
         ``clients`` and ``steps``, the SGD steps dispatched; per client a
-        ``client.update``, with a MoE model's routing counters, then a
-        ``server.fold`` of its delta into the running FedAvg, with the
-        ``client`` and the delta's ``bytes``, and ``stacked_bytes`` where a
-        kernel path stacks) and ``server.aggregate`` (counts ``clients``;
+        ``client.update``, with its ``steps``, ``pulls`` (the device-to-host
+        transfers of its metrics: one a client) and a MoE model's routing
+        counters, then a ``server.fold`` of its delta into the running
+        FedAvg, with the ``client`` and the delta's ``bytes``, and
+        ``stacked_bytes`` where a kernel path stacks) and
+        ``server.aggregate`` (counts ``clients``;
         ``apply_aggregate``, fenced on the new parameters;
         ``server.predict`` inside it under the predictor)."""
         r = self.round_idx

@@ -199,6 +199,16 @@ def _server(cfg):
                     TaskConfig(vocab_size=32, n_topics=4, seq_len=9, seed=0))
 
 
+def _arch_cfg(arch):
+    """A tiny dense llama ("smollm") or the tiny Moonlight, over a
+    32-token vocabulary."""
+    if arch == "smollm":
+        return dataclasses.replace(get_config("smollm_135m").reduced(),
+                                   d_model=32, d_ff=64, vocab_size=32,
+                                   n_layers=2)
+    return dataclasses.replace(tiny()[0], vocab_size=32)
+
+
 @pytest.mark.parametrize("arch", ["smollm", "moonlight"])
 def test_running_fedavg_is_the_cohort_weighted_sum(arch, monkeypatch):
     """One round of ten clients: the aggregate the server applies, folded
@@ -208,13 +218,7 @@ def test_running_fedavg_is_the_cohort_weighted_sum(arch, monkeypatch):
     eps of its terms)."""
     import repro.fl.server as server_mod
     from repro.fl import aggregate_deltas
-    if arch == "smollm":
-        cfg = dataclasses.replace(get_config("smollm_135m").reduced(),
-                                  d_model=32, d_ff=64, vocab_size=32,
-                                  n_layers=2)
-    else:
-        cfg = dataclasses.replace(tiny()[0], vocab_size=32)
-    srv = _server(cfg)
+    srv = _server(_arch_cfg(arch))
     seen, applied = [], []
     real_local = srv.trainer.local_update
     real_apply = server_mod.apply_aggregate
@@ -290,3 +294,126 @@ def test_rows_held_elsewhere_never_reach_a_result(monkeypatch):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
                                    atol=1e-4 * max(float(jnp.max(jnp.abs(w))),
                                                    1e-30))
+
+
+# -- the client's local epoch ------------------------------------------------
+def _trainer_and_batches(arch, steps=5):
+    from repro.fl.client import LocalTrainer
+    cfg = _arch_cfg(arch)
+    params, _ = zoo.init_model(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    batches = [rng.integers(0, 32, (8, 9), dtype=np.int32)
+               for _ in range(steps)]
+    return LocalTrainer(cfg, lr=0.2), params, batches
+
+
+def _per_step_update(trainer, params, batches):
+    """The local epoch as it was written before: each step's metrics
+    pulled to the host right after the step."""
+    p, opt_state = params, trainer.opt.init(params)
+    losses, counters = [], {}
+    for tokens in batches:
+        p, opt_state, metrics = trainer.step(p, opt_state,
+                                             jnp.asarray(tokens))
+        metrics = jax.device_get(metrics)
+        losses.append(float(metrics.pop("loss")))
+        for k, v in metrics.items():
+            counters[k] = counters.get(k, 0) + int(v)
+    delta = jax.tree.map(
+        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+        p, params)
+    return delta, float(np.mean(losses)), counters
+
+
+@pytest.mark.parametrize("arch", ["smollm", "moonlight"])
+def test_local_update_equals_the_per_step_loop(arch):
+    """From the same batches, ``local_update`` gives the per-step loop's
+    mean loss, counters (Python ints) and delta, bit for bit."""
+    trainer, params, batches = _trainer_and_batches(arch)
+    want_delta, want_loss, want_counters = _per_step_update(
+        trainer, params, batches)
+    delta, loss = trainer.local_update(params, batches)
+    assert loss == want_loss
+    assert trainer.counters == want_counters
+    assert all(type(v) is int for v in trainer.counters.values())
+    assert bool(trainer.counters) == (arch == "moonlight")
+    for got, want in zip(jax.tree.leaves(delta), jax.tree.leaves(want_delta)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+class _Watched:
+    """A step's metric that logs any host conversion of itself, and each
+    wait for it (with the index of its step)."""
+
+    def __init__(self, value, log, index):
+        self.value, self.log, self.index = value, log, index
+
+    def block_until_ready(self):
+        self.log.append(("wait", self.index))
+        self.value.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append("read")
+        return np.asarray(self.value, dtype)
+
+    def __float__(self):
+        self.log.append("read")
+        return float(self.value)
+
+    def __int__(self):
+        self.log.append("read")
+        return int(self.value)
+
+
+@pytest.mark.parametrize("in_flight", [2, 1])
+@pytest.mark.parametrize("arch", ["smollm", "moonlight"])
+def test_local_update_pulls_once_after_the_last_step(arch, in_flight,
+                                                     monkeypatch):
+    """A client of 5 steps: every step is dispatched before the one
+    device-to-host transfer, no step's metric is read on the host other
+    than through it, and before step i the host has waited for step
+    i - ``in_flight``: 2 for weights within ``RUN_AHEAD_BYTES``, else 1."""
+    import repro.fl.client as client_mod
+    if in_flight == 1:
+        monkeypatch.setattr(client_mod, "RUN_AHEAD_BYTES", 0)
+    trainer, params, batches = _trainer_and_batches(arch)
+    log = []
+    real_step, real_get = trainer.step, jax.device_get
+
+    def step(p, opt_state, tokens):
+        i = log.count("step")
+        log.append("step")
+        p, opt_state, metrics = real_step(p, opt_state, tokens)
+        return p, opt_state, {k: _Watched(v, log, i)
+                              for k, v in metrics.items()}
+
+    def device_get(x):
+        log.append("get")
+        return real_get(jax.tree.map(
+            lambda v: v.value if isinstance(v, _Watched) else v, x,
+            is_leaf=lambda v: isinstance(v, _Watched)))
+
+    trainer.step = step
+    monkeypatch.setattr(jax, "device_get", device_get)
+    trainer.local_update(params, batches)
+    waits = [e for e in log if isinstance(e, tuple)]
+    assert waits == [("wait", j)
+                     for j in range(len(batches) - in_flight)]
+    assert [e for e in log if not isinstance(e, tuple)] == (
+        ["step"] * len(batches) + ["get"])
+    for w in waits:
+        assert log[:log.index(w)].count("step") == w[1] + in_flight
+
+
+@pytest.mark.parametrize("arch", ["smollm", "moonlight"])
+def test_every_client_update_notes_one_pull(arch):
+    from repro.obs import trace
+    srv = _server(_arch_cfg(arch))
+    with trace.tracing() as tr:
+        srv.run_round()
+    spans = [s for s in tr.spans if s.name == "client.update"]
+    assert len(spans) == 10
+    assert all(s.meta["steps"] >= 3 and s.meta["pulls"] == 1 for s in spans)
+    assert all(("moe_routed" in s.meta) == (arch == "moonlight")
+               for s in spans)
