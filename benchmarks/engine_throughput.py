@@ -163,7 +163,7 @@ def bench_case(n, k, drops, *, model_bits=1e6, seed=0, reps=5,
     # gate never sees a silently missing key.
     engp = WirelessEngine(ncfg, flcfg, kernel_backend="pallas")
     row["kernel_backend"] = engp.impl     # resolved impl, not the request
-    row["pallas_mode"] = engp.pallas_impl
+    row["pallas_mode"] = engp.impl
     if skip_pallas and engp.impl == "interpret":
         row["drops_per_s_jax_pallas"] = None
         row["pallas_skip_reason"] = (

@@ -11,9 +11,9 @@ import time
 
 import numpy as np
 
-from repro.configs import FLConfig, NOMAConfig, get_config
+from repro.configs import POLICIES, FLConfig, NOMAConfig, get_config
 from repro.data import TaskConfig, bayes_optimal_accuracy
-from repro.fl import POLICIES, compare_policies, time_to_accuracy
+from repro.fl import compare_policies, time_to_accuracy
 
 
 def run(*, smoke=False, out_path=None, seed=0, rounds=None, clients=24):

@@ -20,9 +20,8 @@ import os
 
 import numpy as np
 
-from repro.configs import FLConfig, NOMAConfig
+from repro.configs import SELECTIONS, FLConfig, NOMAConfig
 from repro.core import RoundEnv, aoi, noma, plan, schedule_age_noma
-from repro.core.plan import SELECTIONS
 
 PAIRINGS_MEASURED = ("strong_weak", "hungarian")
 

@@ -19,7 +19,7 @@ import os
 
 import numpy as np
 
-from repro.configs import FLConfig, NOMAConfig
+from repro.configs import PAIRINGS, FLConfig, NOMAConfig
 from repro.core import (
     RoundEnv,
     aoi,
@@ -27,7 +27,6 @@ from repro.core import (
     noma,
     schedule_age_noma,
 )
-from repro.core.pairing import PAIRINGS
 
 
 def _make_env(rng, n, ncfg):

@@ -65,8 +65,8 @@ def main():
                          "(pairing-aware admission, core/plan.py)")
     args = ap.parse_args()
 
-    from repro.configs import FLConfig, NOMAConfig
-    from repro.fl.rounds import POLICIES, run_montecarlo
+    from repro.configs import POLICIES, FLConfig, NOMAConfig
+    from repro.fl.rounds import run_montecarlo
 
     def sweep(scenario):
         return run_montecarlo(

@@ -288,8 +288,7 @@ class NOMAConfig:
 
 # Canonical axis registries. Declared here so FLConfig can validate
 # eagerly without importing the implementing subsystems (configs must
-# stay import-leaf); the subsystems re-export them (core/plan.py,
-# core/pairing.py, fl/rounds.py) so call sites keep their natural homes.
+# stay import-leaf); every module takes them from here.
 
 # engine admission-stage implementations (core/plan.resolve_admission;
 # DESIGN.md section 9)
@@ -301,6 +300,12 @@ CELL_LAYOUTS = ("hex", "grid")
 # selection/RA policies (fl/server.py FLServer.select, engine priorities)
 POLICIES = ("age_noma", "age_noma_budget", "random", "channel",
             "round_robin", "oma_age")
+# the policies whose priority is the paper's age key (core/engine.py
+# policy_priority); of them, BUDGET_POLICY plans under a round-time budget
+# and OMA_POLICY gives each admitted client a subchannel of its own
+AGE_POLICIES = ("age_noma", "age_noma_budget", "oma_age")
+BUDGET_POLICY = "age_noma_budget"
+OMA_POLICY = "oma_age"
 
 # subchannel pairing policies (core/pairing.py, DESIGN.md section 7)
 PAIRINGS = ("strong_weak", "adjacent", "hungarian", "greedy_matching")
@@ -322,6 +327,24 @@ KERNEL_BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
 
 # server-side update predictors for unselected clients (fl/predictor.py)
 PREDICTORS = ("none", "stale", "ann")
+
+# every planner choice with its registry: FLConfig and each per-call
+# override check a value against it through check_choice
+CHOICES = {"policy": POLICIES, "engine": ENGINES, "pairing": PAIRINGS,
+           "selection": SELECTIONS, "admission": ADMISSIONS,
+           "cell_layout": CELL_LAYOUTS, "kernel_backend": KERNEL_BACKENDS,
+           "predictor": PREDICTORS}
+
+
+def check_choice(field: str, value: str) -> str:
+    """``value`` if it names one of ``field``'s choices (``CHOICES``), else
+    ValueError — the one check of a planner choice, whether it comes from
+    an ``FLConfig`` or a per-call override."""
+    registry = CHOICES[field]
+    if value not in registry:
+        raise ValueError(f"unknown {field} {value!r} "
+                         f"(expected one of {registry})")
+    return value
 
 # FLConfig fields exempt from __post_init__ validation (reprolint
 # config-validation rule): each entry names WHY eager checking is
@@ -345,15 +368,11 @@ class FLConfig:
     dirichlet_alpha: float = 0.5     # non-IID level
     samples_per_client: Tuple[int, int] = (200, 1200)  # min/max, uniform
     # scheduler
-    policy: str = "age_noma"         # age_noma|random|channel|round_robin|oma_age
+    policy: str = "age_noma"         # POLICIES above
     age_exponent: float = 1.0        # gamma
     t_budget_s: float = 0.0          # 0 = no budget (pure min-round-time)
     engine: str = "numpy"            # numpy (fp64 reference) | jax (batched
                                      # core.engine path for the age policies)
-    engine_pallas: bool = False      # DEPRECATED alias for
-                                     # kernel_backend="pallas"; kept as a
-                                     # back-compat shim (__post_init__ maps
-                                     # it, contradictions raise)
     # kernel lowering backend for the jax engine's Pallas kernels
     # (KERNEL_BACKENDS above; kernels/backend.py resolves it against the
     # host's actual lowering capability at engine construction)
@@ -412,23 +431,14 @@ class FLConfig:
     seed: int = 0
 
     def __post_init__(self) -> None:
-        # fail at construction, not deep inside a Monte-Carlo sweep — the
-        # engine/planner re-validate their per-call overrides with the
-        # same message shape (no silent fallback anywhere on this axis).
-        # Every field is checked here or listed in _POST_INIT_EXEMPT with
-        # a reason (enforced by the reprolint config-validation rule).
-        for field, registry in (("policy", POLICIES),
-                                ("engine", ENGINES),
-                                ("pairing", PAIRINGS),
-                                ("selection", SELECTIONS),
-                                ("admission", ADMISSIONS),
-                                ("cell_layout", CELL_LAYOUTS),
-                                ("kernel_backend", KERNEL_BACKENDS),
-                                ("predictor", PREDICTORS)):
-            value = getattr(self, field)
-            if value not in registry:
-                raise ValueError(f"unknown {field} {value!r} "
-                                 f"(expected one of {registry})")
+        # fail at construction, not deep inside a Monte-Carlo sweep; the
+        # per-call overrides go through the same check_choice. Every field
+        # is checked here or listed in _POST_INIT_EXEMPT with a reason
+        # (enforced by the reprolint config-validation rule).
+        for field in ("policy", "engine", "pairing", "selection",
+                      "admission", "cell_layout", "kernel_backend",
+                      "predictor"):
+            check_choice(field, getattr(self, field))
         for field in ("n_clients", "rounds", "local_epochs", "local_batch",
                       "pred_embed_dim", "pred_hidden_dim", "pred_steps"):
             if getattr(self, field) < 1:
@@ -458,16 +468,6 @@ class FLConfig:
                              f"0 < min <= max, got {(flo, fhi)}")
         if self.n_cells < 1:
             raise ValueError(f"n_cells must be >= 1, got {self.n_cells}")
-        # engine_pallas back-compat shim: the old bool maps onto the
-        # kernel_backend axis; contradictory combinations fail eagerly.
-        if self.engine_pallas:
-            if self.kernel_backend == "auto":
-                object.__setattr__(self, "kernel_backend", "pallas")
-            elif self.kernel_backend == "xla":
-                raise ValueError(
-                    "engine_pallas=True contradicts kernel_backend='xla'; "
-                    "drop the deprecated engine_pallas flag and set "
-                    "kernel_backend alone")
 
 
 # ---------------------------------------------------------------------------

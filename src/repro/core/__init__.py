@@ -20,8 +20,7 @@ from repro.core.engine import (  # noqa: F401
     WirelessEngine,
     engine_schedule_to_numpy,
 )
-from repro.core.pairing import PAIRINGS, pair_candidates  # noqa: F401
-from repro.core.plan import SELECTIONS  # noqa: F401
+from repro.core.pairing import pair_candidates  # noqa: F401
 from repro.core.scheduler import (  # noqa: F401
     RoundEnv,
     Schedule,

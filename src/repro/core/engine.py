@@ -47,13 +47,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ADMISSIONS, FLConfig, NOMAConfig
+from repro.configs.base import (
+    AGE_POLICIES,
+    OMA_POLICY,
+    FLConfig,
+    NOMAConfig,
+    check_choice,
+)
 from repro.core import matching
-from repro.core.pairing import ENUM_MAX_PAIRS, PAIRINGS, enumerate_matchings
+from repro.core.pairing import ENUM_MAX_PAIRS, enumerate_matchings
 from repro.core.plan import (
     JOINT_ENUM_MAX_N,
     JOINT_SWAP_ITERS,
-    SELECTIONS,
     RoundEnv,
     Schedule,
     cell_capacity,
@@ -795,9 +800,8 @@ def _fast_finish(cand, gains, t_cmp, n_samples, model_bits,
             jnp.concatenate([p_i, p_j], axis=1), inv, axis=1)
         strong_tab = jnp.take_along_axis(sid_c, strong_pos, axis=1)
         weak_tab = jnp.take_along_axis(sid_c, weak_pos, axis=1)
-    else:
-        raise ValueError(f"unknown pairing policy {pairing_policy!r} "
-                         f"(expected one of {PAIRINGS})")
+    else:  # unreachable: every entry point checks the pairing
+        check_choice("pairing", pairing_policy)
     if odd:
         solo_r = pairscore.solo_rate_math(sg_c[:, c - 1:c], n0b=n0b,
                                           pmax=pmax, bw=bw)
@@ -954,6 +958,24 @@ def round_robin_priority(round_idx, n: int, n_window: int):
              .astype(jnp.float32))
 
 
+def policy_priority(policy: str, gains, round_idx, n_window: int, draw):
+    """The one map from a selection policy to its admission priority, of
+    ``gains``' shape (clients on the last axis): ``None`` for the age
+    policies (the caller computes the age key, so the engine keeps its
+    fused age path), the gains for ``channel``, ``draw()`` for ``random``
+    (the only policy that draws) and the rotating ``round_robin`` window
+    of ``n_window`` clients that starts at ``round_idx * n_window``."""
+    if check_choice("policy", policy) in AGE_POLICIES:
+        return None
+    if policy == "channel":
+        return gains
+    if policy == "random":
+        return draw()
+    shape = jnp.shape(gains)
+    return jnp.broadcast_to(round_robin_priority(round_idx, shape[-1],
+                                                 n_window), shape)
+
+
 def _compute_times(prm: EngineParams, n_samples, cpu_freq):
     """T_cmp = E * C * D_n / f_n (``core.roundtime.compute_times``)."""
     return (prm.local_epochs * prm.cycles_per_sample * n_samples
@@ -1081,9 +1103,8 @@ def _assemble(cand, gains, t_cmp, model_bits, prm: EngineParams, oma: bool,
                < matching.pair_bottleneck(table, ii, rev, m_valid=m))
         strong_at = jnp.where(use, a_p, i)
         weak_at = jnp.where(use, b_p, rev)
-    else:
-        raise ValueError(f"unknown pairing policy {pairing_policy!r} "
-                         f"(expected one of {PAIRINGS})")
+    else:  # unreachable: every entry point checks the pairing
+        check_choice("pairing", pairing_policy)
     strong = jnp.where(valid, sidx[jnp.clip(strong_at, 0, n - 1)], -1)
     weak = jnp.where(valid, sidx[jnp.clip(weak_at, 0, n - 1)], -1)
     g_i = gains[jnp.clip(strong, 0, n - 1)]
@@ -1446,55 +1467,29 @@ class WirelessEngine:
     scores and builds its completion table in-kernel (``_fast_finish``);
     selection and the eviction loop always run in XLA.
 
-    ``use_pallas``/``pallas_impl`` are the deprecated pre-backend spelling
-    and map onto ``kernel_backend`` (use_pallas=True == "pallas";
-    pallas_impl="interpret" == "pallas_interpret").
+    ``pairing``/``selection``/``admission`` override the ``FLConfig``
+    fields of the same names and are checked as the config checks them.
     """
 
     def __init__(self, ncfg: NOMAConfig, flcfg: FLConfig, *,
                  kernel_backend: Optional[str] = None,
-                 use_pallas: bool = False,
-                 pallas_impl: Optional[str] = None,
                  pairing: Optional[str] = None,
                  selection: Optional[str] = None,
                  admission: Optional[str] = None):
         self.ncfg = ncfg
         self.flcfg = flcfg
         self.prm = EngineParams.from_configs(ncfg, flcfg)
-        self.pairing = flcfg.pairing if pairing is None else pairing
-        if self.pairing not in PAIRINGS:
-            raise ValueError(f"unknown pairing policy {self.pairing!r} "
-                             f"(expected one of {PAIRINGS})")
-        self.selection = (flcfg.selection if selection is None
-                          else selection)
-        if self.selection not in SELECTIONS:
-            raise ValueError(f"unknown selection mode {self.selection!r} "
-                             f"(expected one of {SELECTIONS})")
-        self.admission = (flcfg.admission if admission is None
-                          else admission)
-        if self.admission not in ADMISSIONS:
-            raise ValueError(f"unknown admission mode {self.admission!r} "
-                             f"(expected one of {ADMISSIONS})")
-        if kernel_backend is None:
-            if use_pallas:
-                # deprecated-arg mapping: the old default resolution
-                # ("pallas" on TPU, "interpret" elsewhere) is exactly what
-                # resolve_backend("pallas") does
-                kernel_backend = {None: "pallas", "pallas": "pallas",
-                                  "interpret": "pallas_interpret",
-                                  "xla": "xla"}.get(pallas_impl)
-                if kernel_backend is None:
-                    raise ValueError(
-                        f"unknown pallas_impl {pallas_impl!r} "
-                        f"(expected one of ('xla', 'pallas', 'interpret'))")
-            else:
-                kernel_backend = flcfg.kernel_backend
-        self.backend = resolve_backend(kernel_backend)
+        self.pairing = check_choice(
+            "pairing", flcfg.pairing if pairing is None else pairing)
+        self.selection = check_choice(
+            "selection", flcfg.selection if selection is None else selection)
+        self.admission = check_choice(
+            "admission", flcfg.admission if admission is None else admission)
+        self.backend = resolve_backend(flcfg.kernel_backend
+                                       if kernel_backend is None
+                                       else kernel_backend)
         self.kernel_backend = self.backend.requested
         self.impl = self.backend.impl
-        self.use_pallas = self.backend.uses_pallas
-        # deprecated alias some callers (benchmarks) still read
-        self.pallas_impl = self.impl if self.use_pallas else None
 
     # -- env building ------------------------------------------------------
 
@@ -1611,11 +1606,10 @@ class WirelessEngine:
             if priority is not None:
                 priority = jax.device_put(
                     jnp.asarray(priority, jnp.float32), sh)
-        pairing = self.pairing if pairing is None else pairing
-        selection = self.selection if selection is None else selection
-        if selection not in SELECTIONS:
-            raise ValueError(f"unknown selection mode {selection!r} "
-                             f"(expected one of {SELECTIONS})")
+        pairing = check_choice(
+            "pairing", self.pairing if pairing is None else pairing)
+        selection = check_choice(
+            "selection", self.selection if selection is None else selection)
         no_budget = (isinstance(t_budget, (int, float))
                      and float(t_budget) <= 0.0)
         n_cells = self.flcfg.n_cells if n_cells is None else n_cells
@@ -1639,7 +1633,7 @@ class WirelessEngine:
                 impl=self.impl), mesh,
                 jnp.asarray(priority, jnp.float32), gains, t_cmp,
                 n_samples, model_bits, tb, jnp.asarray(cell, jnp.int32))
-            if self.use_pallas and not no_budget:
+            if self.backend.uses_pallas and not no_budget:
                 # fast cells already scored in-kernel; the budget cells'
                 # eviction loop is XLA and gets the post-hoc rescore
                 out = self._rescore(out, gains, model_bits, oma, mesh)
@@ -1677,14 +1671,14 @@ class WirelessEngine:
                 n_pairs=n_pairs, n_cand0=n_cand0, pairing=pairing,
                 selection=selection), mesh,
                 priority, gains, t_cmp, n_samples, model_bits, t_budget)
-            if self.use_pallas:
+            if self.backend.uses_pallas:
                 out = self._rescore(out, gains, model_bits, oma, mesh)
         return out
 
     def _rescore(self, out: EngineSchedule, gains, model_bits,
                  oma: bool, mesh=None) -> EngineSchedule:
         return _per_shard(functools.partial(
-            _rescore_pallas, oma=oma, prm=self.prm, impl=self.pallas_impl),
+            _rescore_pallas, oma=oma, prm=self.prm, impl=self.impl),
             mesh, out, gains, model_bits)
 
     def schedule(self, env: RoundEnv, *, t_budget: Optional[float] = None,
@@ -1817,12 +1811,13 @@ class WirelessEngine:
         gains per-round handover counts. ``mesh`` (from ``shard=True``)
         keeps the age state on the seed axis and plans each device's seeds
         on that device."""
-        pairing = self.pairing if pairing is None else pairing
-        selection = self.selection if selection is None else selection
-        if selection not in SELECTIONS:
-            raise ValueError(f"unknown selection mode {selection!r} "
-                             f"(expected one of {SELECTIONS})")
-        admission = self.admission if admission is None else admission
+        check_choice("policy", policy)
+        pairing = check_choice(
+            "pairing", self.pairing if pairing is None else pairing)
+        selection = check_choice(
+            "selection", self.selection if selection is None else selection)
+        admission = check_choice(
+            "admission", self.admission if admission is None else admission)
         n_cells = self.flcfg.n_cells
         keys = jax.random.split(jax.random.PRNGKey(seed), rounds)
         mb = jnp.asarray(model_bits, jnp.float32)
@@ -1915,8 +1910,8 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
                      admission: str = "full_sort",
                      impl: str = "xla",
                      n_cells: int = 1, cap: int = 0, mesh=None):
-    """One Monte-Carlo round over all seeds; every policy in
-    ``fl.rounds.POLICIES`` resolves to a priority vector here
+    """One Monte-Carlo round over all seeds; every policy in ``POLICIES``
+    resolves to a priority vector here through ``policy_priority``
     (``age_noma_budget`` is age priority + the caller's positive
     ``t_budget``). ``round_idx`` is traced so the round-robin window can
     advance without recompiling. A non-None ``cell`` with ``n_cells > 1``
@@ -1929,21 +1924,14 @@ def _montecarlo_step(ages, part, gains, key, n_samples, cpu_freq,
     ``mc.finish``, ``mc.ages``), so each op in a device trace carries the
     stage it belongs to."""
     s, n = gains.shape
-    oma = policy == "oma_age"
+    oma = policy == OMA_POLICY
     with jax.named_scope("mc.priority"):
         t_cmp = _compute_times(prm, n_samples, cpu_freq)
         mb = jnp.broadcast_to(model_bits, (s,))
-        if policy in ("age_noma", "age_noma_budget", "oma_age"):
+        prio = policy_priority(policy, gains, round_idx, n_cand0,
+                               lambda: jax.random.uniform(key, gains.shape))
+        if prio is None:
             prio = _age_priority(ages, n_samples, gains, gamma)
-        elif policy == "channel":
-            prio = gains
-        elif policy == "random":
-            prio = jax.random.uniform(key, gains.shape)
-        elif policy == "round_robin":
-            prio = jnp.broadcast_to(
-                round_robin_priority(round_idx, n, n_cand0), gains.shape)
-        else:
-            raise ValueError(f"unknown montecarlo policy {policy!r}")
 
     def plan(prio, gains, t_cmp, n_samples, mb, cell):
         rows = gains.shape[0]

@@ -48,7 +48,7 @@ import functools
 
 import numpy as np
 
-from repro.configs.base import PAIRINGS, NOMAConfig  # noqa: F401  (re-export)
+from repro.configs.base import NOMAConfig, check_choice
 from repro.core import noma
 
 # m <= this: the hungarian policy solves the bottleneck exactly by
@@ -261,6 +261,7 @@ def pair_candidates(gains: np.ndarray, cand: np.ndarray, policy: str, *,
     under ``policy``. Candidates sort by (gain desc, client index asc) —
     the same total order as the engine's bitonic argsort — so ties are
     deterministic and engine-consistent."""
+    check_choice("pairing", policy)
     cand = np.asarray(cand, dtype=int)
     assert len(cand) % 2 == 0, "pair_candidates needs an even candidate set"
     order = noma.pairing_order(gains, cand)
@@ -303,7 +304,4 @@ def pair_candidates(gains: np.ndarray, cand: np.ndarray, policy: str, *,
         if table[a, b].max() >= table[rows, rev].max():
             a, b = rows, rev
         return [(int(order[a[p]]), int(order[b[p]])) for p in range(m)]
-    else:
-        raise ValueError(f"unknown pairing policy {policy!r} "
-                         f"(expected one of {PAIRINGS})")
     return [(int(strong[p]), int(weak[sigma[p]])) for p in range(m)]

@@ -54,9 +54,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.configs.base import (  # noqa: F401  (SELECTIONS re-export)
-    ADMISSIONS, SELECTIONS, FLConfig, NOMAConfig,
-)
+from repro.configs.base import FLConfig, NOMAConfig, check_choice
 from repro.core import aoi, noma, pairing, roundtime
 from repro.obs import trace
 from repro.obs.metrics import aou_histogram
@@ -75,10 +73,7 @@ def resolve_admission(mode: str, n: int, c: int) -> str:
     """Resolve an ``FLConfig.admission`` mode to the concrete stage-2
     implementation for an (N clients, c slots) instance. Explicit modes
     pass through (never silently overridden); unknown modes raise."""
-    if mode not in ADMISSIONS:
-        raise ValueError(f"unknown admission mode {mode!r} "
-                         f"(expected one of {ADMISSIONS})")
-    if mode != "auto":
+    if check_choice("admission", mode) != "auto":
         return mode
     return "segmented" if n >= ADMISSION_AUTO_N else "full_sort"
 
@@ -429,10 +424,8 @@ def plan_round(env: RoundEnv, ncfg: NOMAConfig, flcfg: FLConfig, *,
     """The full staged pipeline for a priority-based policy: admit (greedy
     or joint) -> match -> allocate -> time, then the budget
     eviction/backfill loop (engine twin: ``engine._schedule_one``)."""
-    selection = flcfg.selection if selection is None else selection
-    if selection not in SELECTIONS:
-        raise ValueError(f"unknown selection mode {selection!r} "
-                         f"(expected one of {SELECTIONS})")
+    selection = check_choice(
+        "selection", flcfg.selection if selection is None else selection)
     t_budget = flcfg.t_budget_s if t_budget is None else t_budget
     n = len(env.gains)
     slots = ncfg.n_subchannels * ncfg.users_per_subchannel
@@ -475,10 +468,8 @@ def plan_fixed(cand, env: RoundEnv, ncfg: NOMAConfig, flcfg: FLConfig, *,
     """Pipeline for an explicitly chosen admitted set (random /
     round-robin drivers): optional joint refinement, then stages 3-5 (no
     budget loop — these policies never ran one)."""
-    selection = flcfg.selection if selection is None else selection
-    if selection not in SELECTIONS:
-        raise ValueError(f"unknown selection mode {selection!r} "
-                         f"(expected one of {SELECTIONS})")
+    selection = check_choice(
+        "selection", flcfg.selection if selection is None else selection)
     cand = [int(x) for x in cand]
     base = dict(info or {})
     if selection == "joint":

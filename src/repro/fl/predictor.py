@@ -56,12 +56,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
-from repro.configs.base import FLConfig
+from repro.configs.base import FLConfig, check_choice
 from repro.core import aoi
 from repro.models.layers import dense_init, zeros_init
 from repro.optim import AdamW, apply_updates
-
-MODES = ("none", "stale", "ann")
 
 _EPS = 1e-12
 _N_SCALARS = 4  # log-staleness, data weight, log norm ratio, cosine
@@ -124,9 +122,8 @@ class UpdatePredictor:
 
     def __init__(self, params_template, fl: FLConfig, n_clients: int, *,
                  mode: Optional[str] = None, seed: int = 0):
-        self.mode = fl.predictor if mode is None else mode
-        if self.mode not in MODES:
-            raise ValueError(f"unknown predictor mode {self.mode!r}")
+        self.mode = check_choice("predictor",
+                                 fl.predictor if mode is None else mode)
         self.fl = fl
         self.n_clients = n_clients
 

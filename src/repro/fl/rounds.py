@@ -9,17 +9,12 @@ from typing import Optional
 
 import numpy as np
 
-from repro.configs.base import (  # noqa: F401  (POLICIES re-export)
-    POLICIES, FLConfig, ModelConfig, NOMAConfig,
+from repro.configs.base import (
+    BUDGET_POLICY, POLICIES, FLConfig, ModelConfig, NOMAConfig, check_choice,
 )
 from repro.data import TaskConfig
 from repro.fl.server import FLServer, History
 from repro.obs import RunLedger, trace
-
-# the Monte-Carlo driver covers every FLServer policy (engine-side
-# round_robin/random priorities + budget auto-calibration); the old
-# reduced tuple is kept as an alias for back-compat
-MC_POLICIES = POLICIES
 
 
 def run_experiment(model_cfg: ModelConfig, fl: FLConfig, nomacfg: NOMAConfig,
@@ -63,9 +58,8 @@ def compare_predictors(model_cfg: ModelConfig, fl: FLConfig,
 def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
                    flcfg: Optional[FLConfig] = None, *,
                    n_clients: int = 64, n_seeds: int = 32, rounds: int = 20,
-                   policies=MC_POLICIES, model_bits: float = 1e6,
+                   policies=POLICIES, model_bits: float = 1e6,
                    t_budget: float = 0.0, seed: int = 0,
-                   use_pallas: bool = False,
                    kernel_backend: Optional[str] = None,
                    scenario: str | object = "static_iid",
                    presampled: bool = False, shard: bool = False,
@@ -114,6 +108,8 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
 
     nomacfg = nomacfg or NOMAConfig()
     flcfg = flcfg or FLConfig()
+    for policy in policies:
+        check_choice("policy", policy)
     s, n, r = n_seeds, n_clients, rounds
     with trace.span("mc.call", seed=seed, drops=s * r):
         with trace.span("mc.setup", seed=seed):
@@ -125,15 +121,15 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
             # constructor, never a silent fallback)
             eng = WirelessEngine(nomacfg, flcfg,
                                  kernel_backend=kernel_backend,
-                                 use_pallas=use_pallas, pairing=pairing,
-                                 selection=selection, admission=admission)
+                                 pairing=pairing, selection=selection,
+                                 admission=admission)
             scn = as_scenario(scenario, nomacfg, flcfg)
             k_env = jax.random.PRNGKey(seed)
 
             multicell = flcfg.n_cells > 1
             envs = scn.rollout(k_env, r, (s, n)) if presampled else None
             auto_budget = None
-            if "age_noma_budget" in policies and t_budget <= 0.0:
+            if BUDGET_POLICY in policies and t_budget <= 0.0:
                 # first_env deliberately replays round 0 of rollout's key
                 # schedule so the budget calibration sees the same draws
                 env0 = (tuple(a[0] for a in envs) if envs is not None
@@ -149,8 +145,7 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
                 "n_clients": n, "n_seeds": s, "rounds": r,
                 "model_bits": model_bits, "t_budget": t_budget,
                 "scenario": scn.name, "presampled": bool(presampled),
-                "slots": eng.prm.slots, "use_pallas": use_pallas,
-                "kernel_backend": eng.kernel_backend,
+                "slots": eng.prm.slots, "kernel_backend": eng.kernel_backend,
                 "kernel_impl": eng.impl,
                 "pairing": eng.pairing, "selection": eng.selection,
                 "admission": eng.admission,
@@ -160,7 +155,7 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
         try:
             for policy in policies:
                 tb = t_budget
-                if policy == "age_noma_budget" and tb <= 0.0:
+                if policy == BUDGET_POLICY and tb <= 0.0:
                     tb = auto_budget
                 if envs is not None:
                     out = eng.montecarlo_rounds(
@@ -207,7 +202,7 @@ def run_montecarlo(nomacfg: Optional[NOMAConfig] = None,
                         "handover_rate": (
                             float(np.asarray(out["handovers"]).mean() / n)
                             if "handovers" in out else None),
-                        "t_budget_s": (float(tb) if policy == "age_noma_budget"
+                        "t_budget_s": (float(tb) if policy == BUDGET_POLICY
                                        else None),
                     }
                     ledger.event("policy_done", policy=policy,

@@ -27,9 +27,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import FLConfig, ModelConfig, NOMAConfig
+from repro.configs.base import (
+    AGE_POLICIES,
+    BUDGET_POLICY,
+    OMA_POLICY,
+    FLConfig,
+    ModelConfig,
+    NOMAConfig,
+)
 from repro.core import aoi, plan
-from repro.core.engine import WirelessEngine
+from repro.core.engine import WirelessEngine, policy_priority
 from repro.core.scheduler import (
     RoundEnv,
     Schedule,
@@ -37,7 +44,7 @@ from repro.core.scheduler import (
     schedule_channel_greedy,
     schedule_random,
     schedule_round_robin,
-)  # noqa: F401  (channel_greedy also used for budget auto-calibration)
+)
 from repro.data import (
     TaskConfig,
     balanced_eval_set,
@@ -93,9 +100,19 @@ class History:
 
 
 class FLServer:
+    """One federated run: the planner's choices are ``fl``'s fields.
+
+    ``policy``, ``engine``, ``predictor``, ``scenario``, ``pairing`` and
+    ``selection`` override the ``FLConfig`` fields of the same names; they
+    are folded into ``self.fl``, which checks them at construction. The
+    numpy engine plans with the fp64 reference (``core/scheduler.py``,
+    ``core/plan.py``), the jax engine with ``core/engine.py``.
+    ``agg_impl`` picks the FedAvg reduction and ``seed`` (default
+    ``fl.seed``) the server's own draws."""
+
     def __init__(self, model_cfg: ModelConfig, fl: FLConfig,
                  nomacfg: NOMAConfig, task: TaskConfig, *,
-                 policy: str = "age_noma",
+                 policy: Optional[str] = None,
                  agg_impl: Optional[str] = None,
                  eval_every: int = 5, seed: Optional[int] = None,
                  predictor: Optional[str] = None,
@@ -103,43 +120,24 @@ class FLServer:
                  scenario: Optional[str] = None,
                  pairing: Optional[str] = None,
                  selection: Optional[str] = None):
-        # subchannel pairing policy (core/pairing.py) + admitted-set
-        # selection mode (core/plan.py): explicit overrides rewrite the
-        # config so the numpy planner (which reads fl.pairing/fl.selection)
-        # and the jax engine stay on the same policy
-        if pairing is not None:
-            fl = dataclasses.replace(fl, pairing=pairing)
-        if selection is not None:
-            fl = dataclasses.replace(fl, selection=selection)
-        from repro.core.pairing import PAIRINGS
-        if fl.pairing not in PAIRINGS:
-            raise ValueError(f"unknown pairing {fl.pairing!r} "
-                             f"(expected one of {PAIRINGS})")
-        if fl.selection not in plan.SELECTIONS:
-            raise ValueError(f"unknown selection {fl.selection!r} "
-                             f"(expected one of {plan.SELECTIONS})")
+        given = dict(policy=policy, engine=engine, predictor=predictor,
+                     scenario=scenario, pairing=pairing, selection=selection)
+        fl = dataclasses.replace(
+            fl, **{k: v for k, v in given.items() if v is not None})
         self.cfg = model_cfg
         self.fl = fl
         self.noma = nomacfg
         self.task = task
-        self.policy = policy
         # FedAvg reduction impl: the fused sum in the deltas' own layouts
         # whatever kernel_backend says (no kernel reads the leaves without a
         # relayout, DESIGN.md section 3); agg_impl="pallas"/"interpret"
         # stacks them for the fedagg kernel
         self.agg_impl = resolve_impl(agg_impl or "xla")
         self.eval_every = eval_every
-        self.predictor_mode = fl.predictor if predictor is None else predictor
         # batched wireless engine (core/engine.py) behind FLConfig.engine;
         # the numpy scheduler stays the fp64 reference path
-        self.engine_mode = fl.engine if engine is None else engine
-        if self.engine_mode not in ("numpy", "jax"):
-            raise ValueError(f"unknown engine {self.engine_mode!r} "
-                             "(expected 'numpy' or 'jax')")
-        self.engine = (WirelessEngine(nomacfg, fl,
-                                      kernel_backend=fl.kernel_backend,
-                                      pairing=fl.pairing)
-                       if self.engine_mode == "jax" else None)
+        self.engine = (WirelessEngine(nomacfg, fl) if fl.engine == "jax"
+                       else None)
         seed = fl.seed if seed is None else seed
         self.rng = np.random.default_rng(seed + 10_000)
 
@@ -151,9 +149,8 @@ class FLServer:
         # (repro.sim.numpy_ref) owns topology, fading, and compute/data
         # processes; static_iid consumes exactly the legacy rng stream
         # (distances, cpu at init; one Exp(1) vector per round)
-        self.scenario_name = fl.scenario if scenario is None else scenario
         self.scenario = NumpyScenario(
-            get_scenario_config(self.scenario_name), nomacfg, fl)
+            get_scenario_config(fl.scenario), nomacfg, fl)
         self.distances, self.cpu_freq = self.scenario.init(
             self.rng, fl.n_clients, n_samples=self.n_samples)
         # model + trainer
@@ -165,10 +162,9 @@ class FLServer:
         # server-side update predictor (own seed: must not perturb the
         # topology/selection rng stream, so none/stale/ann stay paired)
         self.predictor = None
-        if self.predictor_mode != "none":
-            self.predictor = UpdatePredictor(
-                self.params, fl, fl.n_clients, mode=self.predictor_mode,
-                seed=seed)
+        if fl.predictor != "none":
+            self.predictor = UpdatePredictor(self.params, fl, fl.n_clients,
+                                             seed=seed)
 
         self.ages = aoi.init_ages(fl.n_clients)
         self._auto_budget = None
@@ -178,6 +174,22 @@ class FLServer:
         self.round_idx = 0
         self.eval_tokens = jnp.asarray(balanced_eval_set(task))
         self._eval_fn = self._make_eval()
+
+    @property
+    def policy(self) -> str:
+        return self.fl.policy
+
+    @property
+    def engine_mode(self) -> str:
+        return self.fl.engine
+
+    @property
+    def predictor_mode(self) -> str:
+        return self.fl.predictor
+
+    @property
+    def scenario_name(self) -> str:
+        return self.fl.scenario
 
     # -- evaluation --------------------------------------------------------
     def _make_eval(self):
@@ -200,117 +212,49 @@ class FLServer:
 
     # -- scheduling --------------------------------------------------------
     def select(self, env: RoundEnv) -> Schedule:
-        """Shared selection path — a thin driver over the round planner
-        (core/plan.py): every policy resolves to a priority vector or an
-        explicit candidate set and hands off to the scheduler's planner
-        drivers (numpy) or the engine stage twins (jax), so each policy
-        can run with or without the update predictor, under any pairing
-        policy and either ``FLConfig.selection`` mode."""
-        if self.fl.n_cells > 1:
-            return self._select_multicell(env)
-        p = self.policy
-        if p in ("age_noma", "age_noma_budget", "oma_age"):
-            oma = p == "oma_age"
-            t_budget = None
-            if p == "age_noma_budget":
-                # the paper's JOINT constraint: age priority under a
-                # round-time budget (auto-calibrated to ~2x the
-                # channel-greedy round time on the first round if the
-                # config leaves it unset)
-                if self._auto_budget is None:
-                    ref = schedule_channel_greedy(env, self.noma, self.fl)
-                    self._auto_budget = (self.fl.t_budget_s
-                                         or 2.0 * max(ref.t_round, 1e-6))
-                t_budget = self._auto_budget
-            if self.engine is not None:
-                if t_budget is not None:
-                    return self.engine.schedule(env, t_budget=t_budget,
-                                                oma=oma, policy=p)
-                return self.engine.schedule(env, oma=oma, policy=p)
-            if t_budget is None:
-                return schedule_age_noma(env, self.noma, self.fl, oma=oma)
-            flb = dataclasses.replace(self.fl, t_budget_s=t_budget)
-            return schedule_age_noma(env, self.noma, flb, oma=oma)
-        # non-age policies: the engine path expresses each as a priority
-        # vector (full engine coverage of POLICIES); the numpy side goes
-        # through the scheduler's thin planner drivers
-        n = self.fl.n_clients
+        """The round's schedule under ``self.policy``: the policy's
+        priority (``engine.policy_priority``) into the jax engine, or on
+        the numpy engine the fp64 reference drivers for one cell and
+        ``plan.plan_multicell`` for several (with the scenario's current
+        serving cells). ``age_noma_budget`` plans under ``fl.t_budget_s``,
+        or, left at 0, under twice the first round's channel-greedy round
+        time."""
+        p, fl, n = self.policy, self.fl, self.fl.n_clients
+        cellv = np.asarray(self.scenario.cell) if fl.n_cells > 1 else None
+        if p == BUDGET_POLICY and self._auto_budget is None:
+            ref = (schedule_channel_greedy(env, self.noma, fl)
+                   if cellv is None else plan.plan_multicell(
+                       env, cellv, fl.n_cells, self.noma, fl,
+                       priority=env.gains))
+            self._auto_budget = fl.t_budget_s or 2.0 * max(ref.t_round,
+                                                           1e-6)
+        t_budget = (self._auto_budget if p == BUDGET_POLICY
+                    else None if p in AGE_POLICIES else 0.0)
+        oma = p == OMA_POLICY
+        if self.engine is None and cellv is None:
+            if p == "random":
+                return schedule_random(self.rng, env, self.noma, fl)
+            if p == "channel":
+                return schedule_channel_greedy(env, self.noma, fl)
+            if p == "round_robin":
+                return schedule_round_robin(self.round_idx, env, self.noma,
+                                            fl)
+            if t_budget is not None:
+                fl = dataclasses.replace(fl, t_budget_s=t_budget)
+            return schedule_age_noma(env, self.noma, fl, oma=oma)
         slots = min(self.noma.n_subchannels
                     * self.noma.users_per_subchannel, n)
-        if p == "random":
-            if self.engine is not None:
-                return self.engine.schedule(
-                    env, t_budget=0.0, policy=p,
-                    priority=self.rng.uniform(size=n))
-            return schedule_random(self.rng, env, self.noma, self.fl)
-        if p == "channel":
-            if self.engine is not None:
-                return self.engine.schedule(env, t_budget=0.0, policy=p,
-                                            priority=env.gains)
-            return schedule_channel_greedy(env, self.noma, self.fl)
-        if p == "round_robin":
-            if self.engine is not None:
-                from repro.core.engine import round_robin_priority
-                return self.engine.schedule(
-                    env, t_budget=0.0, policy=p,
-                    priority=round_robin_priority(self.round_idx, n, slots))
-            return schedule_round_robin(self.round_idx, env, self.noma,
-                                        self.fl)
-        raise ValueError(f"unknown policy {p!r}")
-
-    def _select_multicell(self, env: RoundEnv) -> Schedule:
-        """Multi-cell dispatch (``FLConfig.n_cells > 1``): every policy
-        resolves to a priority vector and hands off to the
-        cell-partitioned planner (``plan.plan_multicell`` / the engine's
-        cell-blocked twin) with the scenario's current serving-BS
-        association — each cell schedules its own K subchannels via the
-        exact single-cell staged pipeline, global round time = max over
-        cells, aggregation weights pooled across cells."""
-        p = self.policy
-        n = self.fl.n_clients
-        cellv = np.asarray(self.scenario.cell)
-        oma = p == "oma_age"
-        t_budget = None
-        priority = None  # None => the paper's age priority
-        if p in ("age_noma", "age_noma_budget", "oma_age"):
-            if p == "age_noma_budget":
-                if self._auto_budget is None:
-                    # budget auto-calibration mirrors the single-cell
-                    # path but against the multi-cell channel-greedy
-                    # round time (max over cells)
-                    ref = plan.plan_multicell(
-                        env, cellv, self.fl.n_cells, self.noma, self.fl,
-                        priority=np.asarray(env.gains, np.float64))
-                    self._auto_budget = (self.fl.t_budget_s
-                                         or 2.0 * max(ref.t_round, 1e-6))
-                t_budget = self._auto_budget
-        elif p == "random":
-            priority = self.rng.uniform(size=n)
-            t_budget = 0.0
-        elif p == "channel":
-            priority = np.asarray(env.gains, np.float64)
-            t_budget = 0.0
-        elif p == "round_robin":
-            # rotating-window priority (engine round_robin_priority twin);
-            # per cell the window picks that cell's earliest members in
-            # the rotation order
-            slots = min(self.noma.n_subchannels
-                        * self.noma.users_per_subchannel, n)
-            start = (self.round_idx * slots) % n
-            priority = -(((np.arange(n) - start) % n).astype(np.float64))
-            t_budget = 0.0
-        else:
-            raise ValueError(f"unknown policy {p!r}")
+        prio = policy_priority(p, env.gains, self.round_idx, slots,
+                               lambda: self.rng.uniform(size=n))
         if self.engine is not None:
-            return self.engine.schedule(
-                env, t_budget=t_budget, oma=oma, policy=p,
-                priority=priority, cell=cellv)
-        if priority is None:
-            priority = plan.age_score(env, self.fl)
-        return plan.plan_multicell(env, cellv, self.fl.n_cells, self.noma,
-                                   self.fl, priority=priority, oma=oma,
-                                   t_budget=t_budget or None,  # 0.0 => none
-                                   info={"policy": p, "engine": "numpy"})
+            return self.engine.schedule(env, t_budget=t_budget, oma=oma,
+                                        policy=p, priority=prio, cell=cellv)
+        return plan.plan_multicell(
+            env, cellv, fl.n_cells, self.noma, fl,
+            priority=plan.age_score(env, fl) if prio is None else prio,
+            oma=oma, t_budget=t_budget or None,  # 0.0 => the config's
+            info={"policy": p, "engine": "numpy"})
+
     def run_round(self) -> Schedule:
         """One round, spanned at each phase (``obs.trace``, every span with
         ``r`` = the round index): ``server.round`` over

@@ -21,7 +21,7 @@ import functools
 
 import numpy as np
 
-from repro.configs.base import CELL_LAYOUTS, FLConfig, NOMAConfig
+from repro.configs.base import FLConfig, NOMAConfig, check_choice
 
 __all__ = ["CellTopology", "bs_layout", "region_radius", "nearest_cell"]
 
@@ -37,9 +37,7 @@ def bs_layout(n_cells: int, layout: str, cell_radius_m: float) -> np.ndarray:
     """
     if n_cells < 1:
         raise ValueError(f"n_cells must be >= 1, got {n_cells}")
-    if layout not in CELL_LAYOUTS:
-        raise ValueError(f"unknown cell layout {layout!r} "
-                         f"(expected one of {CELL_LAYOUTS})")
+    check_choice("cell_layout", layout)
     if n_cells == 1:
         pts = np.zeros((1, 2))
     elif layout == "hex":
@@ -108,9 +106,7 @@ class CellTopology:
     def __post_init__(self) -> None:
         if self.n_cells < 1:
             raise ValueError(f"n_cells must be >= 1, got {self.n_cells}")
-        if self.layout not in CELL_LAYOUTS:
-            raise ValueError(f"unknown cell layout {self.layout!r} "
-                             f"(expected one of {CELL_LAYOUTS})")
+        check_choice("cell_layout", self.layout)
         if self.min_radius_m < 0.0 or self.min_radius_m >= self.cell_radius_m:
             raise ValueError(
                 f"need 0 <= min_radius_m < cell_radius_m, got "

@@ -1,7 +1,7 @@
 """Backend resolution tier: kernels/backend.py resolver order, the
-FLConfig ``kernel_backend`` field + deprecated ``engine_pallas`` shim, the
-WirelessEngine legacy-argument mapping, and engine-level parity between
-the resolved backends (DESIGN.md section 13).
+FLConfig ``kernel_backend`` field, the WirelessEngine ``kernel_backend``
+argument, and engine-level parity between the resolved backends
+(DESIGN.md section 13).
 
 These tests run on any host: branches that require a compiled Pallas
 lowering (Mosaic/Triton) assert the CPU-only fallback when
@@ -94,17 +94,6 @@ class TestConfigField:
         with pytest.raises(ValueError, match="kernel_backend"):
             FLConfig(kernel_backend="triton")
 
-    def test_engine_pallas_shim_maps_to_pallas(self):
-        assert FLConfig(engine_pallas=True).kernel_backend == "pallas"
-
-    def test_engine_pallas_contradiction_rejected(self):
-        with pytest.raises(ValueError, match="contradicts"):
-            FLConfig(engine_pallas=True, kernel_backend="xla")
-
-    def test_engine_pallas_with_explicit_pallas_ok(self):
-        fl = FLConfig(engine_pallas=True, kernel_backend="pallas")
-        assert fl.kernel_backend == "pallas"
-
 
 class TestEngineConstruction:
     def test_default_follows_flconfig(self):
@@ -112,30 +101,10 @@ class TestEngineConstruction:
         assert eng.kernel_backend == "auto"
         if compiled_flavor() is None:
             assert eng.impl == "xla"
-            assert not eng.use_pallas
-            assert eng.pallas_impl is None
-
-    def test_legacy_use_pallas_maps_to_pallas(self):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            eng = WirelessEngine(CFG, FLConfig(), use_pallas=True)
-        assert eng.kernel_backend == "pallas"
-        assert eng.use_pallas
-
-    def test_legacy_pallas_impl_interpret(self):
-        eng = WirelessEngine(CFG, FLConfig(), use_pallas=True,
-                             pallas_impl="interpret")
-        assert eng.kernel_backend == "pallas_interpret"
-        assert eng.impl == "interpret"
-        assert eng.pallas_impl == "interpret"
-
-    def test_legacy_unknown_pallas_impl_rejected(self):
-        with pytest.raises(ValueError, match="pallas_impl"):
-            WirelessEngine(CFG, FLConfig(), use_pallas=True,
-                           pallas_impl="warp")
+            assert not eng.backend.uses_pallas
 
     def test_explicit_kernel_backend_wins_over_flconfig(self):
-        eng = WirelessEngine(CFG, FLConfig(engine_pallas=True),
+        eng = WirelessEngine(CFG, FLConfig(kernel_backend="pallas"),
                              kernel_backend="xla")
         assert eng.impl == "xla"
 

@@ -125,12 +125,13 @@ class TestBudgetPathParity:
 
 
 class TestPallasParity:
-    """use_pallas=True rescoring (interpret mode on CPU) must match too."""
+    """kernel_backend="pallas" rescoring (interpret mode on CPU) must
+    match too."""
 
     @pytest.mark.parametrize("seed", range(3))
     def test_pallas_rescore_matches_numpy(self, seed):
         env = make_env(500 + seed, 16, CFG_SMALL)
-        eng = WirelessEngine(CFG_SMALL, FLCFG, use_pallas=True)
+        eng = WirelessEngine(CFG_SMALL, FLCFG, kernel_backend="pallas")
         ref = schedule_age_noma(env, CFG_SMALL, FLCFG)
         assert_parity(ref, eng.schedule(env))
 

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
-from repro.configs import FLConfig, NOMAConfig
+from repro.configs import PAIRINGS, FLConfig, NOMAConfig
 from repro.core import aoi, matching, noma, pairing, roundtime
 from repro.core.scheduler import (
     RoundEnv,
@@ -102,7 +102,7 @@ class TestPairCandidates:
         env = make_env(rng, n + 4)
         cand = rng.choice(n + 4, size=n, replace=False)
         t_cmp = roundtime.compute_times(env.n_samples, 2e6, env.cpu_freq, 1)
-        for policy in pairing.PAIRINGS:
+        for policy in PAIRINGS:
             pairs = pairing.pair_candidates(env.gains, cand, policy,
                                             t_cmp=t_cmp,
                                             model_bits=env.model_bits,
@@ -216,7 +216,7 @@ class TestMonteCarloPairing:
         strong_weak on the same environments."""
         from repro.fl.rounds import run_montecarlo
         outs = {}
-        for p in pairing.PAIRINGS:
+        for p in PAIRINGS:
             outs[p] = run_montecarlo(
                 n_clients=12, n_seeds=4, rounds=4,
                 policies=("age_noma",), pairing=p, seed=0)

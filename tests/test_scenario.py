@@ -12,9 +12,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.configs import FLConfig, NOMAConfig
+from repro.configs import POLICIES, FLConfig, NOMAConfig
 from repro.core import noma
-from repro.fl.rounds import MC_POLICIES, POLICIES, run_montecarlo
+from repro.fl.rounds import run_montecarlo
 from repro.sim import (
     SCENARIOS,
     NumpyScenario,
@@ -295,9 +295,6 @@ MC_KW = dict(n_clients=16, n_seeds=4, rounds=5, model_bits=4e6, seed=3)
 
 
 class TestMonteCarloParity:
-    def test_mc_policies_cover_all_policies(self):
-        assert MC_POLICIES == POLICIES
-
     @pytest.mark.slow
     @pytest.mark.parametrize("scenario", ["static_iid", "vehicular"])
     def test_fused_matches_presampled_bitwise(self, scenario):
